@@ -79,6 +79,14 @@ def save_result(name: str, payload: Dict[str, Any]) -> str:
     return path
 
 
+def main(run: Callable[[], Any]) -> None:
+    """Command-line entry of one benchmark module: turn on the persistent
+    compilation cache (``repro.core.compile_cache``), then run it."""
+    from repro.core import enable_compile_cache
+    enable_compile_cache()
+    run()
+
+
 def csv_row(name: str, us_per_call: float, derived: str) -> None:
     print(f"{name},{us_per_call:.0f},{derived}", flush=True)
 

@@ -127,4 +127,5 @@ def run(full: bool = False, seed: int = 0):
 
 
 if __name__ == "__main__":
-    run()
+    from .common import main
+    main(run)

@@ -18,8 +18,9 @@ def main() -> None:
                     help="table1|fig3|fig4|fig5|ablation|roofline|robustness|"
                          "robustness_quant|pipeline|placements|fusion|pool")
     ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="enable JAX's persistent compilation cache in DIR "
-                         "(default: $REPRO_COMPILE_CACHE if set); repeated "
+                    help="JAX's persistent compilation cache directory "
+                         "($JAX_COMPILATION_CACHE_DIR wins when set; default "
+                         ".jax-compile-cache/ in the checkout); repeated "
                          "grid cells and re-runs then load compiled round "
                          "programs from disk instead of re-compiling")
     ap.add_argument("--selection", default=None,
@@ -37,7 +38,7 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro.core import enable_compile_cache
-    enable_compile_cache(args.compile_cache)   # no-op when dir/env unset
+    enable_compile_cache(args.compile_cache)
 
     telemetry = None
     if args.trace:

@@ -139,4 +139,5 @@ def engine_speedup(full: bool = False, seed: int = 0):
 
 
 if __name__ == "__main__":
-    run()
+    from .common import main
+    main(run)

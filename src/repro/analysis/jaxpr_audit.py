@@ -6,7 +6,7 @@ witness indirectly, are proven on the program text itself:
 1. **No float64.**  The attack/selection arithmetic is an f32 lane; a bare
    Python literal in a ``jnp.where`` promotes to a weak f64 scalar the
    moment anyone enables x64.  The auditor retraces every entry body under
-   ``jax.experimental.enable_x64()`` — f32 example inputs stay f32, so any
+   ``jax.enable_x64(True)`` — f32 example inputs stay f32, so any
    float64 dtype in the retraced jaxpr is a latent weak-type leak.
 2. **No host callbacks.**  ``pure_callback`` / ``io_callback`` /
    ``debug_callback`` inside a device round serializes the round on host
@@ -194,7 +194,7 @@ def audit_fn(fn: Callable, args: tuple, *, name: str,
             f"{dtype} value flows through '{prim}' in the traced program",
             context=f"{name}:{prim}"))
     if x64_retrace:
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             jx64 = jax.make_jaxpr(fn)(*args)
         for prim, dtype in find_dtypes(jx64):
             findings.append(make_finding(

@@ -8,7 +8,11 @@ and exposes hit/miss counters so :func:`repro.telemetry.metrics.jit_cache_stats`
 can surface whether a run actually paid for its compiles or loaded them.
 
 ``enable_compile_cache(path)`` is idempotent and safe to call before any
-program is traced.  The thresholds are pinned to "cache everything"
+program is traced.  The directory is, in order: ``$JAX_COMPILATION_CACHE_DIR``
+when it is set (JAX's own variable; no other directory is set then), else
+``path``, else the fixed in-checkout ``.jax-compile-cache/`` — a fixed path,
+because the path is part of the cache key and a moving directory never hits.
+The thresholds are pinned to "cache everything"
 (``min_compile_time_secs=0``, ``min_entry_size_bytes=-1``) because the
 protocol layer compiles a small, known set of round programs — there is no
 long tail of tiny throwaway executables to pollute the cache with.
@@ -22,9 +26,13 @@ from __future__ import annotations
 
 import os
 import threading
+from pathlib import Path
 from typing import Any, Dict, Optional
 
-ENV_VAR = "REPRO_COMPILE_CACHE"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+#: the checkout root's ``.jax-compile-cache/`` (listed in .gitignore)
+DEFAULT_DIR = str(Path(__file__).resolve().parents[3] / ".jax-compile-cache")
 
 _lock = threading.Lock()
 _state: Dict[str, Any] = {"dir": None, "hits": 0, "misses": 0,
@@ -38,18 +46,13 @@ def _on_event(event: str, **kwargs) -> None:  # pragma: no cover - thin shim
         _state["misses"] += 1
 
 
-def enable_compile_cache(path: Optional[str] = None) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``path`` and start
-    counting hits/misses.
-
-    ``path=None`` falls back to the ``REPRO_COMPILE_CACHE`` environment
-    variable; if that is unset too, this is a no-op returning ``None`` (the
-    cache stays off).  Returns the directory in use otherwise.  Idempotent:
-    repeated calls re-point the directory but register the event listener
-    only once."""
-    d = path if path is not None else os.environ.get(ENV_VAR)
-    if not d:
-        return None
+def enable_compile_cache(path: Optional[str] = None) -> str:
+    """Turn on JAX's persistent compilation cache and start counting
+    hits/misses.  Returns the directory in use: ``$JAX_COMPILATION_CACHE_DIR``
+    if set, else ``path``, else :data:`DEFAULT_DIR`.  Idempotent: repeated
+    calls re-point the directory but register the event listener only
+    once."""
+    d = os.environ.get(ENV_VAR) or path or DEFAULT_DIR
     import jax
 
     os.makedirs(d, exist_ok=True)

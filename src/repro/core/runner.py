@@ -69,13 +69,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:                                    # jax >= 0.5: public API, new kwargs
-    _shard_map = jax.shard_map          # type: ignore[attr-defined]
-    _SHARD_MAP_LEGACY = False
-except AttributeError:                  # jax 0.4.x: experimental API
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_LEGACY = True
-
 Pytree = Any
 
 PLACEMENTS = ("vmap", "sharded")
@@ -180,16 +173,11 @@ def _auto_axes(mesh: Mesh, manual_axes) -> list:
 
 
 def _apply_shard_map(fn, mesh: Mesh, in_specs, out_specs, manual_axes):
-    """Version shim: jax 0.4.x experimental shard_map (check_rep/auto) vs the
-    jax >= 0.5 public API (check_vma/axis_names).  ``manual_axes`` are the
-    manually-mapped axes; any other mesh axes stay GSPMD-auto."""
-    manual = _normalize_manual_axes(manual_axes)
-    if _SHARD_MAP_LEGACY:
-        auto = frozenset(mesh.axis_names) - manual
-        return _shard_map(fn, mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_rep=False, auto=auto)
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False, axis_names=set(manual))
+    """``jax.shard_map`` over ``manual_axes`` (the manually-mapped axes);
+    any other mesh axes stay GSPMD-auto."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False,
+                         axis_names=set(_normalize_manual_axes(manual_axes)))
 
 
 def backend_supports_partial_auto(mesh: Mesh, manual_axes) -> bool:

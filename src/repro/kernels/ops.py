@@ -1,12 +1,12 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True on CPU (this container) and False on real TPU
-backends — the kernels are written for TPU (pl.pallas_call + BlockSpec VMEM
-tiling) and validated here in interpret mode against the ref.py oracles.
+``interpret`` defaults to True on the CPU backend (tests run the kernels in
+the Pallas interpreter against the ref.py oracles) and to False on TPU, where
+they compile to Mosaic.  Any other backend raises: the kernels are written
+for TPU, and silently interpreting them elsewhere would hide the device.
 """
 from __future__ import annotations
 
-import math
 from functools import lru_cache, partial
 from typing import Optional
 
@@ -19,7 +19,12 @@ from . import tamper_check as _tc
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"the Pallas kernels compile for TPU and are interpreted on CPU; "
+            f"backend {backend!r} is neither (pass interpret= explicitly)")
+    return backend == "cpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k",
@@ -80,10 +85,8 @@ def slstm_scan(pre, r, *, n_heads: int, interpret: Optional[bool] = None):
 
 
 def largest_divisor(n: int, cap: int) -> int:
-    """Largest divisor of n at most ``cap``.  Kernel grids require
-    N % block == 0 (and shared-set sizes like D_o = 1500 are not always
-    multiples of the default tile); the selection subsystem's shard-count
-    clamp (``repro.selection.effective_shards``) delegates here too."""
+    """Largest divisor of n at most ``cap`` — the selection subsystem's
+    shard-count clamp (``repro.selection.effective_shards``)."""
     cap = max(1, min(cap, n))
     while n % cap:
         cap -= 1
@@ -99,9 +102,7 @@ def quant_roundtrip(x, fmt: str, *, block_n: int = 256,
     ``1 byte/element + 4 bytes/row`` on the wire."""
     from . import quant_exchange as _qx
     interpret = _default_interpret() if interpret is None else interpret
-    return _qx.quant_dequant(x, fmt,
-                             block_n=largest_divisor(x.shape[0], block_n),
-                             interpret=interpret)
+    return _qx.quant_dequant(x, fmt, block_n=block_n, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("fmt", "block_n", "interpret"))
@@ -113,8 +114,7 @@ def quant_roundtrip_stats(x, fmt: str, *, block_n: int = 256,
     nothing extra under quantization.  Returns (deq, scales, stats (2,))."""
     from . import quant_exchange as _qx
     interpret = _default_interpret() if interpret is None else interpret
-    return _qx.quant_dequant_stats(x, fmt,
-                                   block_n=largest_divisor(x.shape[0], block_n),
+    return _qx.quant_dequant_stats(x, fmt, block_n=block_n,
                                    interpret=interpret)
 
 
@@ -166,6 +166,5 @@ def tamper_distance(ref, recv, *, block_n: int = 256,
     d = ref.shape[-1]
     a = ref.reshape(-1, d)
     b = recv.reshape(-1, d)
-    sums = _tc.tamper_check_sums(a, b, block_n=largest_divisor(a.shape[0], block_n),
-                                 interpret=interpret)
+    sums = _tc.tamper_check_sums(a, b, block_n=block_n, interpret=interpret)
     return jnp.sqrt(sums[0]) / jnp.maximum(jnp.sqrt(sums[1]), 1e-12)

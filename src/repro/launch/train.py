@@ -7,9 +7,6 @@ at smoke scale on CPU, or over the paper's CNNs:
       --attack label_flip --malicious 2 --rounds 10
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-8b --smoke \
       --protocol pigeon --attack gradient --rounds 3
-
-The full-size configs are trained via the dry-run/production path (pjit on
-the 16x16 mesh) — on this CPU container only the reduced variants execute.
 """
 from __future__ import annotations
 
@@ -60,12 +57,13 @@ def main() -> None:
                          "per host sync (pigeon/sfl batched engine only; "
                          "pigeon+ and param_tamper force 1)")
     ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="enable JAX's persistent compilation cache in DIR "
-                         "(default: $REPRO_COMPILE_CACHE if set)")
+                    help="JAX's persistent compilation cache directory "
+                         "($JAX_COMPILATION_CACHE_DIR wins when set; default "
+                         ".jax-compile-cache/ in the checkout)")
     args = ap.parse_args()
 
     from ..core import enable_compile_cache
-    enable_compile_cache(args.compile_cache)   # no-op when dir/env unset
+    enable_compile_cache(args.compile_cache)
 
     engine = args.engine or ("batched" if args.block > 1 else "sequential")
 

@@ -49,8 +49,7 @@ class SelectionOutcome:
 def effective_shards(k: int, d_o: int) -> int:
     """Largest divisor of D_o at most ``k`` — the shard count both the host
     and fused median-of-means paths actually use (one shared divisor rule:
-    ``repro.kernels.ops.largest_divisor``, which the tamper kernel's grid
-    tiling uses as well)."""
+    ``repro.kernels.ops.largest_divisor``)."""
     from ..kernels.ops import largest_divisor
     return largest_divisor(d_o, k)
 

@@ -352,7 +352,7 @@ def test_find_dtypes_descends_into_subjaxprs():
 
         return jax.lax.scan(body, x, None, length=3)
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jx = jax.make_jaxpr(scanned)(jnp.float32(0.0))
     assert find_dtypes(jx)  # the f64 mul lives inside the scan body jaxpr
     assert find_callbacks(jx) == []

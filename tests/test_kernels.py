@@ -96,6 +96,9 @@ def test_fused_xent_label_edge_cases():
     (256, 32),
     pytest.param(512, 128, marks=pytest.mark.slow),
     pytest.param(64, 64, marks=pytest.mark.slow),
+    (3000, 32),      # D_o = 3000 (Table II) at the MNIST cut width: ragged
+    (3000, 256),     # ... and at the CIFAR-10 cut width
+    (1001, 64),      # N not a multiple of 8
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32,
                                    pytest.param(jnp.bfloat16,

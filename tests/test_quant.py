@@ -41,17 +41,28 @@ FORMATS = [INT8,
 
 @pytest.mark.parametrize("fmt", FORMATS)
 @pytest.mark.parametrize("n,d", [(64, 32), (256, 160),
-                                 pytest.param(512, 33, marks=pytest.mark.slow)])
+                                 pytest.param(512, 33, marks=pytest.mark.slow),
+                                 (3000, 256),    # D_o = 3000: ragged blocks
+                                 (1001, 64)])    # N not a multiple of 8
 def test_quant_roundtrip_matches_reference(fmt, n, d):
-    x = jax.random.normal(jax.random.PRNGKey(0), (n, d)) * 3.0
-    deq, scales = quant_dequant(x, fmt, block_n=64, interpret=True)
+    """Both kernel variants against the oracle; with block_n=64 the larger
+    shapes end in a masked partial block, which must leave the statistics'
+    row count at N."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (n, d)) * 3.0 + 0.5
     deq_ref, scales_ref = ref.quant_roundtrip_reference(x, fmt)
+    deq, scales = quant_dequant(x, fmt, block_n=64, interpret=True)
+    deq_s, scales_s, stats = quant_dequant_stats(x, fmt, block_n=64,
+                                                 interpret=True)
     # same codebook, same scales — up to one float32 ulp of non-associativity
     # between the interpret-mode and pure-jnp multiply orders
-    np.testing.assert_allclose(np.asarray(deq), np.asarray(deq_ref),
-                               rtol=1e-6, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(scales), np.asarray(scales_ref),
-                               rtol=1e-6)
+    for got_deq, got_scales in ((deq, scales), (deq_s, scales_s)):
+        np.testing.assert_allclose(np.asarray(got_deq), np.asarray(deq_ref),
+                                   rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(got_scales),
+                                   np.asarray(scales_ref), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(stats),
+                               np.asarray(ref.message_stats_reference(deq_ref)),
+                               rtol=1e-5, atol=1e-6)
 
 
 @pytest.mark.parametrize("fmt", FORMATS)

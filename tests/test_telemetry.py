@@ -17,6 +17,7 @@ The load-bearing guarantees pinned here (see ``repro/telemetry/__init__``):
 import json
 import os
 import threading
+from pathlib import Path
 
 import jax.numpy as jnp
 import numpy as np
@@ -452,7 +453,7 @@ def test_block_recorded_in_run_start(tiny_task, tiny_pcfg, tmp_path):
     assert start["block"] == 2
 
 
-def test_compile_cache_stats_surface_in_jit_stats(tmp_path):
+def test_compile_cache_stats_surface_in_jit_stats(tmp_path, monkeypatch):
     """enable_compile_cache wires JAX's persistent cache; after clearing the
     in-process jit caches a re-jit loads from disk and the hit counters
     surface through telemetry's jit_cache_stats."""
@@ -461,6 +462,8 @@ def test_compile_cache_stats_surface_in_jit_stats(tmp_path):
     from repro.core import enable_compile_cache
     from repro.core import compile_cache as cc
     from repro.telemetry.metrics import jit_cache_stats
+
+    monkeypatch.delenv(cc.ENV_VAR, raising=False)
 
     prev_dir, prev_hits, prev_misses = (cc._state["dir"], cc._state["hits"],
                                         cc._state["misses"])
@@ -483,15 +486,34 @@ def test_compile_cache_stats_surface_in_jit_stats(tmp_path):
         cc._state["hits"], cc._state["misses"] = prev_hits, prev_misses
 
 
-def test_enable_compile_cache_disabled_without_dir(monkeypatch):
+def test_enable_compile_cache_disabled_without_dir(monkeypatch, tmp_path):
+    """Without an explicit directory the cache goes to the fixed in-checkout
+    ``.jax-compile-cache/``; ``$JAX_COMPILATION_CACHE_DIR`` takes precedence
+    over both the default and an explicit directory."""
+    import jax
+
     from repro.core import compile_cache as cc
-    monkeypatch.delenv(cc.ENV_VAR, raising=False)
+    repo_root = Path(__file__).resolve().parents[1]
+    assert cc.ENV_VAR == "JAX_COMPILATION_CACHE_DIR"
+    assert Path(cc.DEFAULT_DIR) == repo_root / ".jax-compile-cache"
     prev = cc._state["dir"]
-    cc._state["dir"] = None
+    prev_cfg = jax.config.jax_compilation_cache_dir
     try:
-        assert cc.enable_compile_cache(None) is None    # no dir, no env: off
+        monkeypatch.delenv(cc.ENV_VAR, raising=False)
+        assert cc.enable_compile_cache(None) == cc.DEFAULT_DIR
+        assert cc.compile_cache_stats()["persistent_cache_dir"] == cc.DEFAULT_DIR
+        assert jax.config.jax_compilation_cache_dir == cc.DEFAULT_DIR
+        env_dir = str(tmp_path / "from_env")
+        monkeypatch.setenv(cc.ENV_VAR, env_dir)
+        for explicit in (None, str(tmp_path / "explicit")):
+            assert cc.enable_compile_cache(explicit) == env_dir
+            assert jax.config.jax_compilation_cache_dir == env_dir
+        assert not (tmp_path / "explicit").exists()
         stats = cc.compile_cache_stats()
-        assert stats["persistent_cache_dir"] is None
+        assert stats["persistent_cache_dir"] == env_dir
         assert stats["persistent_cache_entries"] == 0
     finally:
+        from jax.experimental.compilation_cache import compilation_cache
+        jax.config.update("jax_compilation_cache_dir", prev_cfg)
+        compilation_cache.reset_cache()
         cc._state["dir"] = prev
