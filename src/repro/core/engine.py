@@ -41,7 +41,8 @@ from .clustering import cluster_is_honest, make_clusters
 from .protocol import (ClientData, CommMeter, History, ProtocolConfig,
                        _count_params, account_client_turn,
                        account_handoff_recheck, account_param_transfer,
-                       account_validation, cut_width, sample_batch_idx)
+                       account_validation, cut_width, eval_span,
+                       sample_batch_idx)
 from .runner import (cluster_map, onehot_select, protocol_accept_runner,
                      protocol_round_spec, protocol_runner)
 from .split import (SplitModule, client_update_vec_impl,
@@ -55,9 +56,23 @@ Pytree = Any
 # host-side assembly: batches, keys and attack state for one round
 # ---------------------------------------------------------------------------
 
+def put_batches(xs: np.ndarray, ys: np.ndarray, telemetry=NULL_SESSION,
+                **attrs) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The host->device copy of stacked mini-batches, under the
+    ``assemble.put`` span: fenced on the arrays it produced, so the span
+    covers the host relayout plus the copy to ready, and carries the bytes
+    moved as ``h2d_bytes``."""
+    with telemetry.span("assemble.put", h2d_bytes=xs.nbytes + ys.nbytes,
+                        **attrs) as sp:
+        xs, ys = jnp.asarray(xs), jnp.asarray(ys)
+        sp.fence(xs, ys)
+    return xs, ys
+
+
 def assemble_round_batches(rng: np.random.Generator, data: ClientData,
                            clusters: Sequence[Sequence[int]],
-                           pcfg: ProtocolConfig, out=None
+                           pcfg: ProtocolConfig, out=None,
+                           telemetry=NULL_SESSION
                            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Sample every client's (E, B) mini-batches for the round, consuming the
     numpy RNG in the sequential engine's order (cluster-major, then client),
@@ -70,7 +85,11 @@ def assemble_round_batches(rng: np.random.Generator, data: ClientData,
     returns them WITHOUT the device conversion — the round-block assemblers
     pass per-round views of one (K, R, M_bar, ...) block buffer so a K-round
     block pays a single host->device transfer instead of K stacks of
-    already-transferred rounds."""
+    already-transferred rounds.
+
+    The gather runs under the ``assemble.gather`` span (``host_bytes``: the
+    bytes written), the conversion under :func:`put_batches`'s
+    ``assemble.put``."""
     r, m_bar = len(clusters), len(clusters[0])
     if out is None:
         xs = np.empty((r, m_bar, pcfg.E, pcfg.B) + data.x.shape[2:],
@@ -79,14 +98,16 @@ def assemble_round_batches(rng: np.random.Generator, data: ClientData,
                       dtype=data.y.dtype)
     else:
         xs, ys = out
-    for i, cluster in enumerate(clusters):
-        for j, client in enumerate(cluster):
-            idx = sample_batch_idx(rng, data.x[client].shape[0], pcfg.E, pcfg.B)
-            np.take(data.x[client], idx, axis=0, out=xs[i, j])
-            np.take(data.y[client], idx, axis=0, out=ys[i, j])
+    with telemetry.span("assemble.gather", host_bytes=xs.nbytes + ys.nbytes):
+        for i, cluster in enumerate(clusters):
+            for j, client in enumerate(cluster):
+                idx = sample_batch_idx(rng, data.x[client].shape[0], pcfg.E,
+                                       pcfg.B)
+                np.take(data.x[client], idx, axis=0, out=xs[i, j])
+                np.take(data.y[client], idx, axis=0, out=ys[i, j])
     if out is not None:
         return xs, ys
-    return jnp.asarray(xs), jnp.asarray(ys)
+    return put_batches(xs, ys, telemetry)
 
 
 @partial(jax.jit, static_argnums=(1, 2))
@@ -115,16 +136,19 @@ def round_client_keys(key: jax.Array, clusters: Sequence[Sequence[int]]
 
 def assemble_round(rng: np.random.Generator, key: jax.Array, data: ClientData,
                    clusters: Sequence[Sequence[int]], pcfg: ProtocolConfig,
-                   tm: ThreatModel, t: int, out=None):
+                   tm: ThreatModel, t: int, out=None, telemetry=NULL_SESSION):
     """One round's complete host-side payload: stacked batches, derived
     per-client keys and the round's AttackVec.  THE single copy of the
     RNG/key consumption order — the synchronous path, the RoundFeeder's
     background thread AND the round-block assembler all call this, so the
     bit-identical prefetch-on/off and block-on/off contracts are structural
     rather than test-enforced.  ``out`` is forwarded to
-    :func:`assemble_round_batches` (block-buffer views).
+    :func:`assemble_round_batches` (block-buffer views), ``telemetry`` to
+    its gather and put spans.  The key split and the attack lanes are the
+    rest of the caller's assembly span.
     Returns (advanced_key, (xs, ys, avec, keys))."""
-    xs, ys = assemble_round_batches(rng, data, clusters, pcfg, out=out)
+    xs, ys = assemble_round_batches(rng, data, clusters, pcfg, out=out,
+                                    telemetry=telemetry)
     key, keys = round_client_keys(key, clusters)
     avec = tm.attack_vec_for_clusters(clusters, t)
     return key, (xs, ys, avec, keys)
@@ -182,7 +206,7 @@ def train_round_batched(module: SplitModule, theta, clusters, data: ClientData,
     if prefetched is None:
         with tel.span("round.assemble", round=t):
             key, prefetched = assemble_round(rng, key, data, clusters, pcfg,
-                                             tm, t)
+                                             tm, t, telemetry=tel)
     xs, ys, avec, keys = prefetched
     with tel.span("round.step", round=t) as sp:
         (gs, ps), aux, vlosses, vacts = protocol_runner(
@@ -236,7 +260,7 @@ def pigeon_round_accept(module: SplitModule, theta, clusters, data: ClientData,
     if prefetched is None:
         with tel.span("round.assemble", round=t):
             key, prefetched = assemble_round(rng, key, data, clusters, pcfg,
-                                             tm, t)
+                                             tm, t, telemetry=tel)
     runner = protocol_accept_runner(module, pcfg.lr, placement, policy,
                                     pcfg.tamper_check, pcfg.tamper_tol,
                                     quant=pcfg.comm.quant)
@@ -274,13 +298,14 @@ def pigeon_round_accept(module: SplitModule, theta, clusters, data: ClientData,
 def train_cluster_batched(module: SplitModule, theta, cluster, data: ClientData,
                           pcfg: ProtocolConfig, tm: ThreatModel, t: int,
                           rng: np.random.Generator, key: jax.Array,
-                          meter: CommMeter, d_c: int
+                          meter: CommMeter, d_c: int, telemetry=NULL_SESSION
                           ) -> Tuple[jax.Array, Pytree, Pytree, float]:
     """One cluster's client chain as a single compiled call (used for the
     Pigeon-SL+ sub-rounds; always the vmap placement — a single cluster has
     no cluster axis to shard).  Key/RNG consumption matches the sequential
     ``split(key)`` + ``train_cluster`` pair exactly."""
-    key, payload = assemble_round(rng, key, data, [cluster], pcfg, tm, t)
+    key, payload = assemble_round(rng, key, data, [cluster], pcfg, tm, t,
+                                  telemetry=telemetry)
     (gs, ps), losses, _, _ = protocol_runner(
         module, pcfg.lr, "vmap", quant=pcfg.comm.quant).candidates(
         theta, payload,
@@ -402,15 +427,17 @@ def assemble_splitfed_round(rng: np.random.Generator, key: jax.Array,
                             data: ClientData,
                             clusters: Sequence[Sequence[int]],
                             pcfg: ProtocolConfig, tm: ThreatModel, t: int,
-                            out=None):
+                            out=None, telemetry=NULL_SESSION):
     """One SplitFed round's host-side payload, consuming the numpy RNG and
     the key stream in the sequential loop's order (cluster-major batch
     sampling; one key split per client, no per-cluster sub-stream).  SplitFed
     sampling never depends on the previous round's selection, so the
     RoundFeeder can run this at any depth — no phase-boundary fallback.
     ``out`` is forwarded to :func:`assemble_round_batches` (block-buffer
-    views).  Returns (advanced_key, (xs, ys, avec, keys))."""
-    xs, ys = assemble_round_batches(rng, data, clusters, pcfg, out=out)
+    views), ``telemetry`` to its spans.  Returns (advanced_key, (xs, ys,
+    avec, keys))."""
+    xs, ys = assemble_round_batches(rng, data, clusters, pcfg, out=out,
+                                    telemetry=telemetry)
     key, keys = splitfed_keys(key, clusters)
     avec = tm.attack_vec_for_clusters(clusters, t)
     return key, (xs, ys, avec, keys)
@@ -432,7 +459,8 @@ def splitfed_round_batched(module: SplitModule, theta, clusters, data: ClientDat
     if prefetched is None:
         with tel.span("round.assemble", round=t):
             key, prefetched = assemble_splitfed_round(rng, key, data,
-                                                      clusters, pcfg, tm, t)
+                                                      clusters, pcfg, tm, t,
+                                                      telemetry=tel)
     xs, ys, avec, keys = prefetched
     with tel.span("round.step", round=t) as sp:
         (g_avg, p_avg), aux, vlosses, _ = splitfed_runner(
@@ -468,7 +496,8 @@ def splitfed_round_accept(module: SplitModule, theta, clusters,
     if prefetched is None:
         with tel.span("round.assemble", round=t):
             key, prefetched = assemble_splitfed_round(rng, key, data,
-                                                      clusters, pcfg, tm, t)
+                                                      clusters, pcfg, tm, t,
+                                                      telemetry=tel)
     runner = splitfed_accept_runner(module, pcfg.lr, placement, policy,
                                     quant=pcfg.comm.quant)
     with tel.span("round.step", round=t) as sp:
@@ -503,7 +532,7 @@ def stack_payloads(payloads):
 
 def assemble_block(rng: np.random.Generator, key: jax.Array, data: ClientData,
                    pcfg: ProtocolConfig, tm: ThreatModel, t0: int, k: int,
-                   out=None):
+                   out=None, telemetry=NULL_SESSION):
     """Host-side payload for a K-round block starting at round ``t0``:
     cluster partitions, stacked mini-batches, derived per-client keys and
     attack state for rounds ``t0 .. t0+k-1``, stacked to a leading K axis.
@@ -527,23 +556,24 @@ def assemble_block(rng: np.random.Generator, key: jax.Array, data: ClientData,
     transfer and the stack, so a J-lane pool block pays one host->device
     copy per leaf instead of J."""
     return _assemble_block_with(assemble_round, rng, key, data, pcfg, tm,
-                                t0, k, out=out)
+                                t0, k, out=out, telemetry=telemetry)
 
 
 def assemble_splitfed_block(rng: np.random.Generator, key: jax.Array,
                             data: ClientData, pcfg: ProtocolConfig,
-                            tm: ThreatModel, t0: int, k: int):
+                            tm: ThreatModel, t0: int, k: int,
+                            telemetry=NULL_SESSION):
     """SplitFed variant of :func:`assemble_block` (cluster-major batch
     sampling, one key split per client — see
     :func:`assemble_splitfed_round`)."""
     return _assemble_block_with(assemble_splitfed_round, rng, key, data,
-                                pcfg, tm, t0, k)
+                                pcfg, tm, t0, k, telemetry=telemetry)
 
 
 def _assemble_block_with(assemble_one, rng: np.random.Generator,
                          key: jax.Array, data: ClientData,
                          pcfg: ProtocolConfig, tm: ThreatModel,
-                         t0: int, k: int, out=None):
+                         t0: int, k: int, out=None, telemetry=NULL_SESSION):
     """Shared K-round assembly: the mini-batches of all K rounds are gathered
     into ONE preallocated (K, R, M_bar, E, B, ...) host buffer (per-round
     ``out=`` views of it), so the block pays a single host->device transfer
@@ -552,7 +582,9 @@ def _assemble_block_with(assemble_one, rng: np.random.Generator,
 
     With ``out=(xs_k, ys_k)`` the caller provides the buffers and gets the
     small leaves back raw (list of K ``(avec, keys)``) — no stacking, no
-    device conversion (see :func:`assemble_block`)."""
+    device conversion (see :func:`assemble_block`).  Each round's gather and
+    the block's one transfer run under ``telemetry``'s ``assemble.gather``
+    and ``assemble.put`` spans."""
     m_bar = pcfg.M // pcfg.R
     if out is None:
         xs_k = np.empty((k, pcfg.R, m_bar, pcfg.E, pcfg.B) + data.x.shape[2:],
@@ -566,14 +598,15 @@ def _assemble_block_with(assemble_one, rng: np.random.Generator,
         clusters = make_clusters(rng, pcfg.M, pcfg.R)
         key, (_, _, avec, keys) = assemble_one(rng, key, data, clusters,
                                                pcfg, tm, t0 + i,
-                                               out=(xs_k[i], ys_k[i]))
+                                               out=(xs_k[i], ys_k[i]),
+                                               telemetry=telemetry)
         clusters_k.append(clusters)
         small.append((avec, keys))
     if out is not None:
         return key, clusters_k, small
     avec_k, keys_k = stack_payloads(small)
-    return key, clusters_k, (jnp.asarray(xs_k), jnp.asarray(ys_k),
-                             avec_k, keys_k)
+    xs_k, ys_k = put_batches(xs_k, ys_k, telemetry, round=t0, k=k)
+    return key, clusters_k, (xs_k, ys_k, avec_k, keys_k)
 
 
 def pigeon_block_accept(module: SplitModule, theta, clusters_k,
@@ -790,7 +823,7 @@ def run_pigeon_sweep(module: SplitModule, data: ClientData, pcfg: ProtocolConfig
                         for j in range(len(seeds)):
                             keys[j], (x_j, y_j, avec_j, krow) = assemble_round(
                                 rngs[j], keys[j], data, clusters_s[j], pcfg,
-                                tm, t0 + i)
+                                tm, t0 + i, telemetry=tel)
                             xs.append(x_j)
                             ys.append(y_j)
                             key_rows.append(krow)
@@ -826,7 +859,7 @@ def run_pigeon_sweep(module: SplitModule, data: ClientData, pcfg: ProtocolConfig
                     if t % pcfg.eval_every == 0 or t == pcfg.T - 1:
                         # plan_blocks ends every block at an eval sync round,
                         # so thetas here is exactly the post-round-t state
-                        with tel.span("round.eval", round=t):
+                        with eval_span(tel, data, t):
                             accs = evaluate_sweep(module, gammas, phis,
                                                   data.x_test, data.y_test,
                                                   pcfg.eval_batch)
@@ -868,7 +901,8 @@ def run_pigeon_sweep(module: SplitModule, data: ClientData, pcfg: ProtocolConfig
                 xs, ys, key_rows, avecs = [], [], [], []
                 for i in range(len(seeds)):
                     keys[i], (x_i, y_i, avec_i, krow) = assemble_round(
-                        rngs[i], keys[i], data, clusters_s[i], pcfg, tm, t)
+                        rngs[i], keys[i], data, clusters_s[i], pcfg, tm, t,
+                        telemetry=tel)
                     xs.append(x_i)
                     ys.append(y_i)
                     key_rows.append(krow)
@@ -904,7 +938,7 @@ def run_pigeon_sweep(module: SplitModule, data: ClientData, pcfg: ProtocolConfig
             tlosses = np.asarray(tlosses)
             accs = None
             if t % pcfg.eval_every == 0 or t == pcfg.T - 1:
-                with tel.span("round.eval", round=t):
+                with eval_span(tel, data, t):
                     accs = evaluate_sweep(module, gammas, phis, data.x_test,
                                           data.y_test, pcfg.eval_batch)
             for i in range(len(seeds)):

@@ -52,7 +52,8 @@ from .comm import CommConfig
 from .protocol import (ClientData, CommMeter, History, ProtocolConfig,
                        _count_params, account_client_turn,
                        account_handoff_recheck, account_param_transfer,
-                       account_validation, check_block, cut_width, evaluate)
+                       account_validation, check_block, cut_width, eval_span,
+                       evaluate)
 from .runner import check_placement, protocol_accept_runner
 from .split import SplitModule
 
@@ -368,7 +369,7 @@ def _replay_lane_rounds(st: _JobState, clusters_k, records, t0: int,
             # sync rounds never fall mid-block and the stacked carry holds
             # exactly this lane's post-round-t theta
             theta = theta_lane_of()
-            with tel.span("round.eval", round=t, job=spec.name):
+            with eval_span(tel, spec.data, t, job=spec.name):
                 rec["test_acc"] = evaluate(
                     spec.module, theta[0], theta[1], spec.data.x_test,
                     spec.data.y_test, pcfg.eval_batch)
@@ -388,7 +389,7 @@ def _run_bucket(states: List[_JobState], order: List[int], block: int,
     """Execute one shape bucket's jobs through the shared pool program."""
     from ..checkpoint import protocol_state_metadata
     from ..data.pipeline import RoundFeeder
-    from .engine import assemble_block
+    from .engine import assemble_block, put_batches
 
     runnable = [i for i in order if not states[i].terminal]
     if not runnable:
@@ -431,7 +432,8 @@ def _run_bucket(states: List[_JobState], order: List[int], block: int,
             st = states[j]
             st.key, clusters_k, small = assemble_block(
                 st.rng, st.key, st.spec.data, st.pcfg, st.tm,
-                plan.t0s[lane], plan.k, out=(xs_j[lane], ys_j[lane]))
+                plan.t0s[lane], plan.k, out=(xs_j[lane], ys_j[lane]),
+                telemetry=tel)
             snap = None
             if st.spec.checkpoint_path is not None:
                 snap = protocol_state_metadata(st.rng, st.key)
@@ -444,7 +446,8 @@ def _run_bucket(states: List[_JobState], order: List[int], block: int,
                 ys_j[lane] = ys_j[first]
                 smalls[lane] = smalls[first]
         avec_j, keys_j = _stack_small_lanes(tuple(tuple(s) for s in smalls))
-        binputs = (jnp.asarray(xs_j), jnp.asarray(ys_j), avec_j, keys_j)
+        xs_j, ys_j = put_batches(xs_j, ys_j, tel, block=b, k=plan.k)
+        binputs = (xs_j, ys_j, avec_j, keys_j)
         return per_lane, binputs
 
     feeder = RoundFeeder(_make_block, 0, len(plans), depth=prefetch,

@@ -304,6 +304,15 @@ def _eval_count_fn(module: SplitModule):
     return count
 
 
+def eval_span(tel, data: ClientData, t: int, **attrs):
+    """The ``round.eval`` span of round ``t``.  It carries, as
+    ``h2d_bytes``, the bytes :func:`evaluate` copies to the device: the whole
+    test set."""
+    return tel.span("round.eval", round=t,
+                    h2d_bytes=data.x_test.nbytes + data.y_test.nbytes,
+                    **attrs)
+
+
 def evaluate(module: SplitModule, gamma, phi, x_test: np.ndarray, y_test: np.ndarray,
              batch: int = 500) -> float:
     if x_test.shape[0] == 0:
@@ -588,7 +597,7 @@ def run_pigeon(module: SplitModule, data: ClientData, pcfg: ProtocolConfig,
         def _make_block(b):
             t0, k = segments[b]
             _state["key"], clusters_k, payload = assemble_block(
-                rng, _state["key"], data, pcfg, tm, t0, k)
+                rng, _state["key"], data, pcfg, tm, t0, k, telemetry=tel)
             # Stream snapshot for the block-end checkpoint: the fused path
             # splits no keys after assembly, so the post-block-assembly
             # stream state IS the synchronous end-of-round state of the
@@ -655,7 +664,7 @@ def run_pigeon(module: SplitModule, data: ClientData, pcfg: ProtocolConfig,
                         # only reachable at the block's last scanned round:
                         # plan_blocks breaks blocks at eval sync rounds, so
                         # theta is exactly the post-round-t state
-                        with tel.span("round.eval", round=t):
+                        with eval_span(tel, data, t):
                             rec["test_acc"] = evaluate(
                                 module, theta[0], theta[1], data.x_test,
                                 data.y_test, pcfg.eval_batch)
@@ -689,7 +698,8 @@ def run_pigeon(module: SplitModule, data: ClientData, pcfg: ProtocolConfig,
         def _make_round(t):
             clusters = make_clusters(rng, pcfg.M, pcfg.R)
             _state["key"], payload = assemble_round(
-                rng, _state["key"], data, clusters, pcfg, tm, t)
+                rng, _state["key"], data, clusters, pcfg, tm, t,
+                telemetry=tel)
             # Stream snapshot for the round-t checkpoint: by the time the
             # main loop saves round t, the feeder has already consumed the
             # RNG/key streams for rounds t+1.., so the snapshot must be taken
@@ -768,7 +778,7 @@ def run_pigeon(module: SplitModule, data: ClientData, pcfg: ProtocolConfig,
                             from .engine import train_cluster_batched
                             key, g, p, _ = train_cluster_batched(
                                 module, theta, sel_cluster, data, pcfg, tm,
-                                t, rng, key, meter, d_c)
+                                t, rng, key, meter, d_c, telemetry=tel)
                         else:
                             key, sub = jax.random.split(key)
                             g, p, _ = train_cluster(module, theta[0],
@@ -793,7 +803,7 @@ def run_pigeon(module: SplitModule, data: ClientData, pcfg: ProtocolConfig,
                 comm=dataclasses.asdict(meter),
             )
             if t % pcfg.eval_every == 0 or t == pcfg.T - 1:
-                with tel.span("round.eval", round=t):
+                with eval_span(tel, data, t):
                     rec["test_acc"] = evaluate(module, theta[0], theta[1],
                                                data.x_test, data.y_test,
                                                pcfg.eval_batch)
@@ -875,7 +885,7 @@ def run_vanilla_sl(module: SplitModule, data: ClientData, pcfg: ProtocolConfig,
             rec = dict(round=t, train_loss=train_loss,
                        comm=dataclasses.asdict(meter))
             if t % pcfg.eval_every == 0 or t == pcfg.T - 1:
-                with tel.span("round.eval", round=t):
+                with eval_span(tel, data, t):
                     rec["test_acc"] = evaluate(module, gamma, phi,
                                                data.x_test, data.y_test,
                                                pcfg.eval_batch)
@@ -956,7 +966,7 @@ def run_splitfed(module: SplitModule, data: ClientData, pcfg: ProtocolConfig,
         def _make_block(b):
             t0, k = segments[b]
             _state["key"], clusters_k, payload = assemble_splitfed_block(
-                rng, _state["key"], data, pcfg, tm, t0, k)
+                rng, _state["key"], data, pcfg, tm, t0, k, telemetry=tel)
             return clusters_k, payload
 
         feeder = RoundFeeder(_make_block, 0, len(segments), depth=prefetch,
@@ -988,7 +998,7 @@ def run_splitfed(module: SplitModule, data: ClientData, pcfg: ProtocolConfig,
                                    sel_cluster, tm.malicious),
                                comm=dataclasses.asdict(meter))
                     if t % pcfg.eval_every == 0 or t == pcfg.T - 1:
-                        with tel.span("round.eval", round=t):
+                        with eval_span(tel, data, t):
                             rec["test_acc"] = evaluate(
                                 module, theta[0], theta[1], data.x_test,
                                 data.y_test, pcfg.eval_batch)
@@ -1011,7 +1021,8 @@ def run_splitfed(module: SplitModule, data: ClientData, pcfg: ProtocolConfig,
         def _make_round(t):
             clusters = make_clusters(rng, pcfg.M, pcfg.R)
             _state["key"], payload = assemble_splitfed_round(
-                rng, _state["key"], data, clusters, pcfg, tm, t)
+                rng, _state["key"], data, clusters, pcfg, tm, t,
+                telemetry=tel)
             return clusters, payload
 
         feeder = RoundFeeder(_make_round, 0, pcfg.T, depth=prefetch,
@@ -1096,7 +1107,7 @@ def run_splitfed(module: SplitModule, data: ClientData, pcfg: ProtocolConfig,
                                                          tm.malicious),
                        comm=dataclasses.asdict(meter))
             if t % pcfg.eval_every == 0 or t == pcfg.T - 1:
-                with tel.span("round.eval", round=t):
+                with eval_span(tel, data, t):
                     rec["test_acc"] = evaluate(module, theta[0], theta[1],
                                                data.x_test, data.y_test,
                                                pcfg.eval_batch)

@@ -528,18 +528,20 @@ class RoundRunner:
         spec, policy = self.spec, self.select
         new_p, aux, vlosses, vaux, shard_l = select_map(
             spec, policy, params, inputs, val, False)
-        ctx = policy_context(spec, policy, aux, vlosses, shard_l)
-        scores, elig = policy_scores(policy, ctx)
-        if self.verify.enabled:
-            passed, _ = self._verify_passed(new_p, vaux, val)
-        else:
-            passed = jnp.ones_like(elig)
-        sel, det, acc = masked_first_accept(scores, elig, passed)
-        winner = onehot_select(new_p, sel)
-        committed = jax.tree.map(lambda w, old: jnp.where(acc, w, old),
-                                 winner, params)
-        fetch = pack_fetch(vlosses, _spec_train_summary(spec, aux, vlosses),
-                           sel, det, acc)
+        with jax.named_scope("accept_cascade"):
+            ctx = policy_context(spec, policy, aux, vlosses, shard_l)
+            scores, elig = policy_scores(policy, ctx)
+            if self.verify.enabled:
+                passed, _ = self._verify_passed(new_p, vaux, val)
+            else:
+                passed = jnp.ones_like(elig)
+            sel, det, acc = masked_first_accept(scores, elig, passed)
+            winner = onehot_select(new_p, sel)
+            committed = jax.tree.map(lambda w, old: jnp.where(acc, w, old),
+                                     winner, params)
+            fetch = pack_fetch(vlosses,
+                               _spec_train_summary(spec, aux, vlosses),
+                               sel, det, acc)
         return committed, fetch
 
     def sweep_fn(self) -> Callable:
@@ -759,20 +761,21 @@ class RoundRunner:
         def per_shard(params_s, inputs_s, val_s):
             new_p, aux, vloss, vaux, shard_l = select_map(
                 spec, policy, params_s, inputs_s, val_s, False)
-            ctx = self._gathered_context(aux, vloss, shard_l, ax)
-            scores, elig = policy_scores(policy, ctx)
-            if self.verify.enabled:
-                passed_l, _ = self._verify_passed(new_p, vaux, val_s)
-                passed = jax.lax.all_gather(passed_l, ax, tiled=True)
-            else:
-                passed = jnp.ones_like(elig)
-            sel, det, acc = masked_first_accept(scores, elig, passed)
-            winner = self._psum_pick(new_p, sel, ax)
-            committed = jax.tree.map(lambda w, old: jnp.where(acc, w, old),
-                                     winner, params_s)
-            summary = jax.lax.all_gather(
-                _spec_train_summary(spec, aux, vloss), ax, tiled=True)
-            fetch = pack_fetch(ctx.vlosses, summary, sel, det, acc)
+            with jax.named_scope("accept_cascade"):
+                ctx = self._gathered_context(aux, vloss, shard_l, ax)
+                scores, elig = policy_scores(policy, ctx)
+                if self.verify.enabled:
+                    passed_l, _ = self._verify_passed(new_p, vaux, val_s)
+                    passed = jax.lax.all_gather(passed_l, ax, tiled=True)
+                else:
+                    passed = jnp.ones_like(elig)
+                sel, det, acc = masked_first_accept(scores, elig, passed)
+                winner = self._psum_pick(new_p, sel, ax)
+                committed = jax.tree.map(
+                    lambda w, old: jnp.where(acc, w, old), winner, params_s)
+                summary = jax.lax.all_gather(
+                    _spec_train_summary(spec, aux, vloss), ax, tiled=True)
+                fetch = pack_fetch(ctx.vlosses, summary, sel, det, acc)
             return committed, fetch
 
         in_specs = (P(), P(ax), P())
@@ -995,24 +998,28 @@ def protocol_round_spec(module, lr: float, with_stats: bool = False,
                                                 k, quant=quant)
             return (g, p), loss
 
-        (g, p), aux = jax.lax.scan(per_client, (gamma, phi),
-                                   (xs_c, ys_c, av_c, keys_c))
+        with jax.named_scope("client_chain"):
+            (g, p), aux = jax.lax.scan(per_client, (gamma, phi),
+                                       (xs_c, ys_c, av_c, keys_c))
         return (g, p), aux
 
     def validate(theta, val):
         g, p = theta
         x0, y0 = val
-        acts = module.client_forward(g, x0)
-        return module.ap_loss(p, acts, y0), acts
+        with jax.named_scope("validation"):
+            acts = module.client_forward(g, x0)
+            return module.ap_loss(p, acts, y0), acts
 
     def validate_sharded(theta, val, k):
         g, p = theta
         x0, y0 = val
-        acts = module.client_forward(g, x0)
-        shard_losses = sharded_validation_losses(module, p, acts, y0, k)
-        # History's vloss stays the exact full-set loss (same op as
-        # ``validate``, the forward is shared); the shards only feed scores
-        return module.ap_loss(p, acts, y0), shard_losses, acts
+        with jax.named_scope("validation"):
+            acts = module.client_forward(g, x0)
+            shard_losses = sharded_validation_losses(module, p, acts, y0, k)
+            # History's vloss stays the exact full-set loss (same op as
+            # ``validate``, the forward is shared); the shards only feed
+            # scores
+            return module.ap_loss(p, acts, y0), shard_losses, acts
 
     def handoff_acts(theta, val):
         return module.client_forward(theta[0], val[0])

@@ -96,4 +96,5 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((1, d), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention",
     )(idx, q, k, v)

@@ -115,6 +115,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             _vmem((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
 
 

@@ -86,4 +86,5 @@ def fused_xent(hidden: jnp.ndarray, weights: jnp.ndarray, labels: jnp.ndarray, *
             pltpu.VMEM((block_t,), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_xent",
     )(hidden, weights, labels)

@@ -106,6 +106,7 @@ def quant_dequant(x: jnp.ndarray, fmt: str, *, block_n: int = 256,
         out_shape=[jax.ShapeDtypeStruct((n, d), jnp.float32),
                    jax.ShapeDtypeStruct((n, 1), jnp.float32)],
         interpret=interpret,
+        name="quant_dequant",
     )(x)
     return deq, scale.reshape(n)
 
@@ -173,6 +174,7 @@ def quant_dequant_stats(x: jnp.ndarray, fmt: str, *, block_n: int = 256,
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32),
                         pltpu.SMEM((3,), jnp.float32)],
         interpret=interpret,
+        name="quant_dequant_stats",
     )(x)
     # sums = [sum_i ||deq_i - mu||, ||min(deq, 0)||^2, ||deq||^2, ||mu||^2]
     sums = sums[0]

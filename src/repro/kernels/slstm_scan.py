@@ -89,4 +89,5 @@ def slstm_scan(pre: jnp.ndarray, r: jnp.ndarray, *, n_heads: int,
             pltpu.VMEM((b, d), jnp.float32),   # m
         ],
         interpret=interpret,
+        name="slstm_scan",
     )(pre, r)

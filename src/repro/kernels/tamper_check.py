@@ -66,4 +66,5 @@ def tamper_check_sums(ref: jnp.ndarray, recv: jnp.ndarray, *,
         out_shape=jax.ShapeDtypeStruct((1, 2), jnp.float32),
         scratch_shapes=[pltpu.SMEM((2,), jnp.float32)],
         interpret=interpret,
+        name="tamper_distance",
     )(ref, recv)[0]

@@ -4,7 +4,8 @@ per-round metrics and provenance-stamped event logs.
 The protocol stack's observability layer (see README "Observability"):
 
 * :mod:`trace`      — nested monotonic-clock spans with explicit
-                      ``block_until_ready`` fencing at span exit, plus the
+                      ``block_until_ready`` fencing at span exit, each also a
+                      ``jax.profiler.TraceAnnotation``, plus the
                       :class:`Stopwatch` timer helper the launch scripts use.
 * :mod:`metrics`    — per-round gauges and run counters, populated from the
                       batched path's existing single stacked host fetch (no
@@ -32,8 +33,7 @@ from .profile import ProfileHook
 from .provenance import provenance
 from .session import (DISABLED, NULL_SESSION, NullSession, Telemetry,
                       TelemetrySession, resolve_telemetry)
-from .sinks import (ConsoleSink, JSONLSink, MemorySink, MultiSink, Sink,
-                    read_jsonl)
+from .sinks import ConsoleSink, JSONLSink, MemorySink, Sink, read_jsonl
 from .trace import NULL_SPAN, NULL_TRACER, Span, Stopwatch, Tracer
 
 __all__ = [
@@ -41,7 +41,6 @@ __all__ = [
     "DISABLED", "resolve_telemetry",
     "Tracer", "Span", "Stopwatch", "NULL_TRACER", "NULL_SPAN",
     "MetricsRegistry", "round_gauges", "pool_gauges", "jit_cache_stats",
-    "Sink", "JSONLSink", "MemorySink", "ConsoleSink", "MultiSink",
-    "read_jsonl",
+    "Sink", "JSONLSink", "MemorySink", "ConsoleSink", "read_jsonl",
     "ProfileHook", "provenance",
 ]

@@ -165,15 +165,3 @@ class ConsoleSink(Sink):
             parts.append(f"vloss=[{vl}]")
         print(" ".join(parts), flush=True, file=self._stream)
 
-
-class MultiSink(Sink):
-    def __init__(self, sinks):
-        self.sinks = list(sinks)
-
-    def emit(self, event: Dict[str, Any]) -> None:
-        for s in self.sinks:
-            s.emit(event)
-
-    def close(self) -> None:
-        for s in self.sinks:
-            s.close()
