@@ -28,6 +28,7 @@ tolerance, and report bit-identical message counts.
 from __future__ import annotations
 
 import dataclasses
+import threading
 from functools import lru_cache, partial
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
@@ -53,61 +54,119 @@ Pytree = Any
 
 
 # ---------------------------------------------------------------------------
-# host-side assembly: batches, keys and attack state for one round
+# round assembly: indices drawn on the host, mini-batches gathered on the
+# device from a resident copy of the client shards
 # ---------------------------------------------------------------------------
 
-def put_batches(xs: np.ndarray, ys: np.ndarray, telemetry=NULL_SESSION,
-                **attrs) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """The host->device copy of stacked mini-batches, under the
-    ``assemble.put`` span: fenced on the arrays it produced, so the span
-    covers the host relayout plus the copy to ready, and carries the bytes
-    moved as ``h2d_bytes``."""
-    with telemetry.span("assemble.put", h2d_bytes=xs.nbytes + ys.nbytes,
-                        **attrs) as sp:
-        xs, ys = jnp.asarray(xs), jnp.asarray(ys)
-        sp.fence(xs, ys)
-    return xs, ys
+_RESIDENT_LOCK = threading.Lock()
+
+
+def _flat_per_sample(a: np.ndarray) -> np.ndarray:
+    """(M, D_m, ...) -> (M*D_m, F), F the product of the sample dims, or
+    (M*D_m,) for scalar samples.  The chip tiles an array's two minor dims
+    to (8, 128): an image's channel dim (1 or 3) as the minor dim would pad
+    the copy up to ~40x, a row of F floats hardly at all."""
+    rows = (a.shape[0] * a.shape[1],)
+    return a.reshape(rows + ((int(np.prod(a.shape[2:])),) if a.ndim > 2
+                             else ()))
+
+
+def resident_data(data: ClientData, telemetry=NULL_SESSION
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """The device copy of every client shard that the batched engine gathers
+    its mini-batches from: ``x`` flat per sample, ``y`` as (M*D_m, ...).
+    Put once per ``ClientData`` object, under the fenced
+    ``assemble.resident`` span (``h2d_bytes``), and memoised on it, so every
+    later round, run and job on the same object reads the one copy."""
+    with _RESIDENT_LOCK:
+        got = data._resident
+        if got is None or got[0] is not data.x or got[1] is not data.y:
+            x, y = _flat_per_sample(data.x), _flat_per_sample(data.y)
+            with telemetry.span("assemble.resident",
+                                h2d_bytes=x.nbytes + y.nbytes) as sp:
+                res = jnp.asarray(x), jnp.asarray(y)
+                sp.fence(res)
+            got = data._resident = (data.x, data.y) + res
+    return got[2:]
+
+
+def draw_round_idx(rng: np.random.Generator, data: ClientData,
+                   clusters: Sequence[Sequence[int]], pcfg: ProtocolConfig,
+                   out: Optional[np.ndarray] = None,
+                   telemetry=NULL_SESSION) -> np.ndarray:
+    """Every client's (E, B) mini-batch indices for the round, consuming the
+    numpy RNG in the sequential engine's order (cluster-major, then client),
+    as rows of :func:`resident_data`'s copy (``client * D_m + idx``): an
+    int32 (R, M_bar, E, B) buffer, or ``out``, one round's view of a
+    block's or a pool block's buffer.  Runs under an ``assemble.gather``
+    span."""
+    d_m = data.x.shape[1]
+    if out is None:
+        out = np.empty((len(clusters), len(clusters[0]), pcfg.E, pcfg.B),
+                       dtype=np.int32)
+    with telemetry.span("assemble.gather"):
+        for i, cluster in enumerate(clusters):
+            for j, client in enumerate(cluster):
+                out[i, j] = client * d_m + sample_batch_idx(rng, d_m, pcfg.E,
+                                                            pcfg.B)
+    return out
+
+
+def put_idx(idx: np.ndarray, telemetry=NULL_SESSION, **attrs) -> jax.Array:
+    """The host->device copy of drawn indices, under the ``assemble.put``
+    span: fenced to ready, with the bytes moved as ``h2d_bytes``."""
+    with telemetry.span("assemble.put", h2d_bytes=idx.nbytes, **attrs) as sp:
+        idx = jnp.asarray(idx)
+        sp.fence(idx)
+    return idx
+
+
+@partial(jax.jit, static_argnums=(3, 4))
+def _take_rows(x_res, y_res, idx, x_shape, y_shape):
+    # "clip": the drawn rows are in range; the default "fill" would mask
+    # every gathered element
+    flat = idx.reshape(-1)
+    return (jnp.take(x_res, flat, axis=0, mode="clip").reshape(
+                idx.shape + x_shape),
+            jnp.take(y_res, flat, axis=0, mode="clip").reshape(
+                idx.shape + y_shape))
+
+
+def take_batches(data: ClientData, idx: jax.Array, telemetry=NULL_SESSION,
+                 **attrs) -> Tuple[jax.Array, jax.Array]:
+    """The mini-batches at device indices ``idx`` (any leading shape: a
+    round, a K-round block, a J-lane pool block), shaped ``idx.shape +
+    x.shape[2:]`` like the host arrays they stand for, gathered by one
+    jitted call under the ``assemble.gather`` span (``device_bytes``: the
+    bytes it writes).  Not fenced: the gather queues behind the round
+    program the device is running, and a fence would time that."""
+    x_res, y_res = resident_data(data, telemetry)
+    row_bytes = (x_res.nbytes + y_res.nbytes) // x_res.shape[0]
+    with telemetry.span("assemble.gather", device_bytes=idx.size * row_bytes,
+                        **attrs):
+        return _take_rows(x_res, y_res, idx, data.x.shape[2:],
+                          data.y.shape[2:])
+
+
+def gather_batches(data: ClientData, idx: np.ndarray, telemetry=NULL_SESSION,
+                   **attrs) -> Tuple[jax.Array, jax.Array]:
+    """:func:`put_idx` then :func:`take_batches`: host indices in, device
+    mini-batches out."""
+    return take_batches(data, put_idx(idx, telemetry, **attrs), telemetry,
+                        **attrs)
 
 
 def assemble_round_batches(rng: np.random.Generator, data: ClientData,
                            clusters: Sequence[Sequence[int]],
-                           pcfg: ProtocolConfig, out=None,
-                           telemetry=NULL_SESSION
-                           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Sample every client's (E, B) mini-batches for the round, consuming the
-    numpy RNG in the sequential engine's order (cluster-major, then client),
-    stacked to (R, M_bar, E, B, ...).  Each gather writes straight into one
-    preallocated per-round buffer (``np.take(..., out=...)``), so the host
-    pays a single copy per sample instead of the old per-cluster
-    ``np.stack`` followed by another stack + device conversion.
-
-    ``out=(xs_view, ys_view)`` writes into caller-provided numpy buffers and
-    returns them WITHOUT the device conversion — the round-block assemblers
-    pass per-round views of one (K, R, M_bar, ...) block buffer so a K-round
-    block pays a single host->device transfer instead of K stacks of
-    already-transferred rounds.
-
-    The gather runs under the ``assemble.gather`` span (``host_bytes``: the
-    bytes written), the conversion under :func:`put_batches`'s
-    ``assemble.put``."""
-    r, m_bar = len(clusters), len(clusters[0])
-    if out is None:
-        xs = np.empty((r, m_bar, pcfg.E, pcfg.B) + data.x.shape[2:],
-                      dtype=data.x.dtype)
-        ys = np.empty((r, m_bar, pcfg.E, pcfg.B) + data.y.shape[2:],
-                      dtype=data.y.dtype)
-    else:
-        xs, ys = out
-    with telemetry.span("assemble.gather", host_bytes=xs.nbytes + ys.nbytes):
-        for i, cluster in enumerate(clusters):
-            for j, client in enumerate(cluster):
-                idx = sample_batch_idx(rng, data.x[client].shape[0], pcfg.E,
-                                       pcfg.B)
-                np.take(data.x[client], idx, axis=0, out=xs[i, j])
-                np.take(data.y[client], idx, axis=0, out=ys[i, j])
-    if out is not None:
-        return xs, ys
-    return put_batches(xs, ys, telemetry)
+                           pcfg: ProtocolConfig, telemetry=NULL_SESSION
+                           ) -> Tuple[jax.Array, jax.Array]:
+    """Every client's (E, B) mini-batches for the round, stacked to
+    (R, M_bar, E, B, ...) on the device: the indices drawn on the host in
+    the sequential engine's RNG order (:func:`draw_round_idx`), put, and
+    gathered from the resident shards (:func:`gather_batches`)."""
+    return gather_batches(data, draw_round_idx(rng, data, clusters, pcfg,
+                                               telemetry=telemetry),
+                          telemetry)
 
 
 @partial(jax.jit, static_argnums=(1, 2))
@@ -134,24 +193,34 @@ def round_client_keys(key: jax.Array, clusters: Sequence[Sequence[int]]
     return _round_client_keys(key, len(clusters), len(clusters[0]))
 
 
+def _assemble_with(split_keys, rng, key, data, clusters, pcfg, tm, t, out,
+                   telemetry):
+    if out is None:
+        batches = assemble_round_batches(rng, data, clusters, pcfg, telemetry)
+    else:
+        draw_round_idx(rng, data, clusters, pcfg, out, telemetry)
+        batches = ()
+    key, keys = split_keys(key, clusters)
+    return key, (*batches, tm.attack_vec_for_clusters(clusters, t), keys)
+
+
 def assemble_round(rng: np.random.Generator, key: jax.Array, data: ClientData,
                    clusters: Sequence[Sequence[int]], pcfg: ProtocolConfig,
                    tm: ThreatModel, t: int, out=None, telemetry=NULL_SESSION):
-    """One round's complete host-side payload: stacked batches, derived
-    per-client keys and the round's AttackVec.  THE single copy of the
-    RNG/key consumption order — the synchronous path, the RoundFeeder's
-    background thread AND the round-block assembler all call this, so the
-    bit-identical prefetch-on/off and block-on/off contracts are structural
-    rather than test-enforced.  ``out`` is forwarded to
-    :func:`assemble_round_batches` (block-buffer views), ``telemetry`` to
-    its gather and put spans.  The key split and the attack lanes are the
-    rest of the caller's assembly span.
-    Returns (advanced_key, (xs, ys, avec, keys))."""
-    xs, ys = assemble_round_batches(rng, data, clusters, pcfg, out=out,
-                                    telemetry=telemetry)
-    key, keys = round_client_keys(key, clusters)
-    avec = tm.attack_vec_for_clusters(clusters, t)
-    return key, (xs, ys, avec, keys)
+    """One round's complete payload: stacked batches, derived per-client
+    keys and the round's AttackVec.  THE single copy of the RNG/key
+    consumption order — the synchronous path, the RoundFeeder's background
+    thread AND the round-block assembler all call this, so the bit-identical
+    prefetch-on/off and block-on/off contracts are structural rather than
+    test-enforced.  ``telemetry`` gets the draw, put and gather spans; the
+    key split and the attack lanes are the rest of the caller's assembly
+    span.  Returns (advanced_key, (xs, ys, avec, keys)).
+
+    ``out`` — one round's view of a block's index buffer — takes the drawn
+    indices and leaves the gather to the block's caller: then the payload
+    is just ``(avec, keys)``."""
+    return _assemble_with(round_client_keys, rng, key, data, clusters, pcfg,
+                          tm, t, out, telemetry)
 
 
 # ---------------------------------------------------------------------------
@@ -428,19 +497,15 @@ def assemble_splitfed_round(rng: np.random.Generator, key: jax.Array,
                             clusters: Sequence[Sequence[int]],
                             pcfg: ProtocolConfig, tm: ThreatModel, t: int,
                             out=None, telemetry=NULL_SESSION):
-    """One SplitFed round's host-side payload, consuming the numpy RNG and
-    the key stream in the sequential loop's order (cluster-major batch
-    sampling; one key split per client, no per-cluster sub-stream).  SplitFed
-    sampling never depends on the previous round's selection, so the
-    RoundFeeder can run this at any depth — no phase-boundary fallback.
-    ``out`` is forwarded to :func:`assemble_round_batches` (block-buffer
-    views), ``telemetry`` to its spans.  Returns (advanced_key, (xs, ys,
-    avec, keys))."""
-    xs, ys = assemble_round_batches(rng, data, clusters, pcfg, out=out,
-                                    telemetry=telemetry)
-    key, keys = splitfed_keys(key, clusters)
-    avec = tm.attack_vec_for_clusters(clusters, t)
-    return key, (xs, ys, avec, keys)
+    """One SplitFed round's payload, consuming the numpy RNG and the key
+    stream in the sequential loop's order (cluster-major batch sampling; one
+    key split per client, no per-cluster sub-stream).  SplitFed sampling
+    never depends on the previous round's selection, so the RoundFeeder can
+    run this at any depth — no phase-boundary fallback.  ``out`` and
+    ``telemetry`` as in :func:`assemble_round`.  Returns (advanced_key,
+    (xs, ys, avec, keys))."""
+    return _assemble_with(splitfed_keys, rng, key, data, clusters, pcfg, tm,
+                          t, out, telemetry)
 
 
 def splitfed_round_batched(module: SplitModule, theta, clusters, data: ClientData,
@@ -549,12 +614,11 @@ def assemble_block(rng: np.random.Generator, key: jax.Array, data: ClientData,
     is the K per-round cluster partitions (the host replay needs them for
     History/honesty/CommMeter bookkeeping).
 
-    ``out=(xs_k, ys_k)`` writes the batches into caller-provided numpy
-    buffers — e.g. one lane view of a job pool's ``(J, K, ...)`` block
-    buffer — and returns the SMALL leaves raw (a list of K ``(avec, keys)``
-    payloads, no stacking, no device conversion): the caller owns both the
-    transfer and the stack, so a J-lane pool block pays one host->device
-    copy per leaf instead of J."""
+    ``out`` — one lane's view of a job pool's ``(J, K, R, M_bar, E, B)``
+    index buffer — takes the drawn indices and returns the SMALL leaves raw
+    (a list of K ``(avec, keys)`` payloads, no stacking): the caller owns
+    the put, the gather and the stack, so a J-lane pool block pays one put
+    and one gather instead of J."""
     return _assemble_block_with(assemble_round, rng, key, data, pcfg, tm,
                                 t0, k, out=out, telemetry=telemetry)
 
@@ -574,38 +638,28 @@ def _assemble_block_with(assemble_one, rng: np.random.Generator,
                          key: jax.Array, data: ClientData,
                          pcfg: ProtocolConfig, tm: ThreatModel,
                          t0: int, k: int, out=None, telemetry=NULL_SESSION):
-    """Shared K-round assembly: the mini-batches of all K rounds are gathered
-    into ONE preallocated (K, R, M_bar, E, B, ...) host buffer (per-round
-    ``out=`` views of it), so the block pays a single host->device transfer
-    instead of K transfers followed by a device-side re-stack; the small
-    leaves (AttackVec state, per-client keys) are stacked on device.
+    """Shared K-round assembly: the indices of all K rounds are drawn into
+    ONE (K, R, M_bar, E, B) buffer (per-round ``out=`` views of it), so the
+    block pays a single put and a single device gather; the small leaves
+    (AttackVec state, per-client keys) are stacked on device.
 
-    With ``out=(xs_k, ys_k)`` the caller provides the buffers and gets the
-    small leaves back raw (list of K ``(avec, keys)``) — no stacking, no
-    device conversion (see :func:`assemble_block`).  Each round's gather and
-    the block's one transfer run under ``telemetry``'s ``assemble.gather``
-    and ``assemble.put`` spans."""
+    With ``out`` the caller provides the index buffer and gets the small
+    leaves back raw (list of K ``(avec, keys)``; see
+    :func:`assemble_block`)."""
     m_bar = pcfg.M // pcfg.R
-    if out is None:
-        xs_k = np.empty((k, pcfg.R, m_bar, pcfg.E, pcfg.B) + data.x.shape[2:],
-                        dtype=data.x.dtype)
-        ys_k = np.empty((k, pcfg.R, m_bar, pcfg.E, pcfg.B) + data.y.shape[2:],
-                        dtype=data.y.dtype)
-    else:
-        xs_k, ys_k = out
+    idx_k = (np.empty((k, pcfg.R, m_bar, pcfg.E, pcfg.B), dtype=np.int32)
+             if out is None else out)
     clusters_k, small = [], []
     for i in range(k):
         clusters = make_clusters(rng, pcfg.M, pcfg.R)
-        key, (_, _, avec, keys) = assemble_one(rng, key, data, clusters,
-                                               pcfg, tm, t0 + i,
-                                               out=(xs_k[i], ys_k[i]),
-                                               telemetry=telemetry)
+        key, small_i = assemble_one(rng, key, data, clusters, pcfg, tm,
+                                    t0 + i, out=idx_k[i], telemetry=telemetry)
         clusters_k.append(clusters)
-        small.append((avec, keys))
+        small.append(small_i)
     if out is not None:
         return key, clusters_k, small
     avec_k, keys_k = stack_payloads(small)
-    xs_k, ys_k = put_batches(xs_k, ys_k, telemetry, round=t0, k=k)
+    xs_k, ys_k = gather_batches(data, idx_k, telemetry, round=t0, k=k)
     return key, clusters_k, (xs_k, ys_k, avec_k, keys_k)
 
 
@@ -796,6 +850,7 @@ def run_pigeon_sweep(module: SplitModule, data: ClientData, pcfg: ProtocolConfig
     tel = resolve_telemetry(telemetry, run="sweep", placement=placement,
                             T=pcfg.T, M=pcfg.M, R=pcfg.R, seeds=list(seeds),
                             selection=policy.name)
+    resident_data(data, tel)
 
     if block > 1:
         # Round-block execution: chain K global rounds as one scanned sweep

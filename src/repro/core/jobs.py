@@ -389,7 +389,8 @@ def _run_bucket(states: List[_JobState], order: List[int], block: int,
     """Execute one shape bucket's jobs through the shared pool program."""
     from ..checkpoint import protocol_state_metadata
     from ..data.pipeline import RoundFeeder
-    from .engine import assemble_block, put_batches
+    from .engine import (assemble_block, put_idx, resident_data,
+                         take_batches)
 
     runnable = [i for i in order if not states[i].terminal]
     if not runnable:
@@ -403,7 +404,7 @@ def _run_bucket(states: List[_JobState], order: List[int], block: int,
         st0.pcfg.tamper_check, st0.pcfg.tamper_tol,
         quant=st0.pcfg.comm.quant)
 
-    pcfg0, data0 = st0.pcfg, st0.spec.data
+    pcfg0 = st0.pcfg
     m_bar = pcfg0.M // pcfg0.R
 
     def _make_block(b):
@@ -411,45 +412,54 @@ def _run_bucket(states: List[_JobState], order: List[int], block: int,
         payload in lane order, every lane consuming ITS OWN job's RNG/key
         streams exactly as the solo block path would; idle lanes copy the
         first active lane's payload as a placeholder (masked on device, no
-        stream consumption).  The big leaves (mini-batches) are gathered
-        straight into one (J, K, R, M_bar, E, B, ...) host buffer — lane
-        views through ``assemble_block(out=...)`` — so the whole pool block
-        pays ONE host->device transfer per leaf; the small leaves stack in
-        one jitted dispatch.  Stream snapshots for block-end checkpoints are
-        captured here, right after each lane's assembly — the fused path
-        splits no keys after assembly, so this is the synchronous
-        end-of-block stream state (the solo feeder argument)."""
+        stream consumption).  Every lane draws its indices into a view of
+        one (J, K, R, M_bar, E, B) buffer (``assemble_block(out=...)``), so
+        the whole pool block pays ONE put and, when its lanes share one
+        ``ClientData``, ONE device gather (else one per lane, each from its
+        job's resident copy); the small leaves stack in one jitted
+        dispatch.  Stream snapshots for block-end checkpoints are captured
+        here, right after each lane's assembly — the fused path splits no
+        keys after assembly, so this is the synchronous end-of-block stream
+        state (the solo feeder argument)."""
         plan = plans[b]
-        xs_j = np.empty((n_lanes, plan.k, pcfg0.R, m_bar, pcfg0.E, pcfg0.B)
-                        + data0.x.shape[2:], dtype=data0.x.dtype)
-        ys_j = np.empty((n_lanes, plan.k, pcfg0.R, m_bar, pcfg0.E, pcfg0.B)
-                        + data0.y.shape[2:], dtype=data0.y.dtype)
+        idx_j = np.empty((n_lanes, plan.k, pcfg0.R, m_bar, pcfg0.E, pcfg0.B),
+                         dtype=np.int32)
         per_lane: List[Optional[tuple]] = [None] * n_lanes
         smalls: List[Optional[list]] = [None] * n_lanes
+        datas: List[Optional[ClientData]] = [None] * n_lanes
         for lane, j in enumerate(plan.assign):
             if j < 0:
                 continue
             st = states[j]
             st.key, clusters_k, small = assemble_block(
                 st.rng, st.key, st.spec.data, st.pcfg, st.tm,
-                plan.t0s[lane], plan.k, out=(xs_j[lane], ys_j[lane]),
-                telemetry=tel)
+                plan.t0s[lane], plan.k, out=idx_j[lane], telemetry=tel)
             snap = None
             if st.spec.checkpoint_path is not None:
                 snap = protocol_state_metadata(st.rng, st.key)
             per_lane[lane] = (clusters_k, snap)
             smalls[lane] = small
+            datas[lane] = st.spec.data
         first = next(l for l, s in enumerate(smalls) if s is not None)
         for lane in range(n_lanes):
             if smalls[lane] is None:
-                xs_j[lane] = xs_j[first]
-                ys_j[lane] = ys_j[first]
+                idx_j[lane] = idx_j[first]
                 smalls[lane] = smalls[first]
+                datas[lane] = datas[first]
         avec_j, keys_j = _stack_small_lanes(tuple(tuple(s) for s in smalls))
-        xs_j, ys_j = put_batches(xs_j, ys_j, tel, block=b, k=plan.k)
+        attrs = dict(block=b, k=plan.k)
+        idx_d = put_idx(idx_j, tel, **attrs)
+        if all(d is datas[0] for d in datas):
+            xs_j, ys_j = take_batches(datas[0], idx_d, tel, **attrs)
+        else:
+            xs_j, ys_j = _stack_lanes(tuple(
+                take_batches(d, idx_d[lane], tel, **attrs)
+                for lane, d in enumerate(datas)))
         binputs = (xs_j, ys_j, avec_j, keys_j)
         return per_lane, binputs
 
+    for i in runnable:              # each distinct ClientData, put once
+        resident_data(states[i].spec.data, tel)
     feeder = RoundFeeder(_make_block, 0, len(plans), depth=prefetch,
                          telemetry=tel)
     jobs_done = 0

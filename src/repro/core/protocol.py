@@ -66,6 +66,10 @@ class ClientData:
     y0: np.ndarray            # (D_o,)
     x_test: np.ndarray
     y_test: np.ndarray
+    # the batched engine's device copy of x/y, put once per object
+    # (``engine.resident_data``)
+    _resident: Any = dataclasses.field(default=None, init=False, repr=False,
+                                       compare=False)
 
 
 @dataclasses.dataclass
@@ -455,8 +459,8 @@ def run_pigeon(module: SplitModule, data: ClientData, pcfg: ProtocolConfig,
       (the equivalence suite's oracle knob).
     * ``placement`` — batched engine only: ``"vmap"`` (cluster axis vmapped
       on one device) or ``"sharded"`` (cluster axis laid over a device mesh).
-    * ``prefetch`` — batched engine only: double-buffer host-side round
-      assembly (batch gathering, key derivation, device transfer) ``prefetch``
+    * ``prefetch`` — batched engine only: double-buffer round assembly
+      (index draw, index put, device gather, key derivation) ``prefetch``
       rounds ahead on a background thread (``data/pipeline.py::RoundFeeder``).
       The RNG/key consumption order is preserved exactly, so the trajectory
       is bit-identical to ``prefetch=0``.  The feeder bounds its depth to
@@ -568,6 +572,9 @@ def run_pigeon(module: SplitModule, data: ClientData, pcfg: ProtocolConfig,
         engine=engine, placement=placement, prefetch=prefetch, block=block,
         T=pcfg.T, M=pcfg.M, R=pcfg.R, selection=policy.name,
         fused_selection=fused_selection)
+    if engine == "batched":
+        from .engine import resident_data
+        resident_data(data, tel)       # the one put, before the first round
 
     def _ckpt_due(t: int) -> bool:
         return checkpoint_path is not None and (
@@ -948,6 +955,9 @@ def run_splitfed(module: SplitModule, data: ClientData, pcfg: ProtocolConfig,
         verbose=verbose, run="sfl", engine=engine, placement=placement,
         prefetch=prefetch, block=block, T=pcfg.T, M=pcfg.M, R=pcfg.R,
         selection=policy.name, fused_selection=fused_selection)
+    if engine == "batched":
+        from .engine import resident_data
+        resident_data(data, tel)
 
     if block > 1:
         # Round-block execution: K FedAvg + selection-cascade rounds as one

@@ -4,9 +4,10 @@ double-buffered host-side round feeder.
 The pipeline mirrors the paper's system model: client m holds a local shard
 D_m (i.i.d. from p(x, y)); the AP samples the shared/reference set D_o from
 the same distribution and broadcasts it before training.  The
-:class:`RoundFeeder` overlaps the host-side assembly of round t+1 (batch
-gathering, RNG/key derivation, device transfer) with device execution of
-round t — cluster selection is the protocol's only true sync point."""
+:class:`RoundFeeder` overlaps the assembly of round t+1 (the mini-batch
+index draw and its put, the dispatch of the device gather from the resident
+client shards, RNG/key derivation) with device execution of round t —
+cluster selection is the protocol's only true sync point."""
 from __future__ import annotations
 
 import dataclasses
@@ -140,9 +141,9 @@ class RoundFeeder:
     strictly in ascending-``t`` order.  That preserves the numpy-RNG and
     JAX-key consumption order the sequential-oracle equivalence contract
     depends on: the streams see exactly the calls the synchronous path would
-    make, just earlier in wall-clock time.  Device transfers issued inside
-    ``make_round`` (``jnp.asarray`` / ``jax.device_put``) are asynchronous,
-    so they overlap with the device executing the current round.
+    make, just earlier in wall-clock time.  Device work issued inside
+    ``make_round`` (the mini-batch gather, the key split) is asynchronous:
+    it queues behind the round the device is executing.
 
     At most ``depth`` assembled rounds wait in the queue ahead of the
     consumer (``depth=1`` is classic double buffering).  ``depth=0``

@@ -472,8 +472,9 @@ def test_round_feeder_depth_zero_is_synchronous():
 # ---------------------------------------------------------------------------
 
 def test_assemble_round_batches_matches_reference(tiny_task, tiny_pcfg):
-    """The preallocated np.take path must consume the RNG identically to the
-    historical stack-of-stacks implementation and produce the same arrays."""
+    """The device gather from the resident shards must consume the RNG
+    identically to the historical host path and produce, bit for bit, the
+    per-client ``data.x[client][idx]`` arrays, on the device."""
     data, _ = tiny_task
     clusters = [[0, 1], [2, 3]]
     xs, ys = assemble_round_batches(np.random.default_rng(7), data, clusters,
@@ -490,9 +491,102 @@ def test_assemble_round_batches_matches_reference(tiny_task, tiny_pcfg):
             ys_c.append(data.y[client][idx])
         xs_ref.append(np.stack(xs_c))
         ys_ref.append(np.stack(ys_c))
+    assert isinstance(xs, jax.Array) and isinstance(ys, jax.Array)
     np.testing.assert_array_equal(np.asarray(xs), np.stack(xs_ref))
     np.testing.assert_array_equal(np.asarray(ys), np.stack(ys_ref))
     assert xs.shape == (2, 2, tiny_pcfg.E, tiny_pcfg.B) + data.x.shape[2:]
+    assert (xs.dtype, ys.dtype) == (data.x.dtype, data.y.dtype)
+
+
+def _host_rounds(data, pcfg, split_keys):
+    """``pcfg.T`` rounds as the host assembled them before the device
+    gather: the same cluster and index draws, each client's batches taken
+    with ``np.take`` from its shard; the per-client keys; and where both
+    randomness streams end."""
+    from repro.checkpoint import protocol_state_metadata
+    from repro.core.clustering import make_clusters
+    rng = np.random.default_rng(pcfg.seed)
+    key, _ = jax.random.split(jax.random.PRNGKey(pcfg.seed))
+    m_bar = pcfg.M // pcfg.R
+    rounds = []
+    for _ in range(pcfg.T):
+        clusters = make_clusters(rng, pcfg.M, pcfg.R)
+        xs = np.empty((pcfg.R, m_bar, pcfg.E, pcfg.B) + data.x.shape[2:],
+                      dtype=data.x.dtype)
+        ys = np.empty((pcfg.R, m_bar, pcfg.E, pcfg.B) + data.y.shape[2:],
+                      dtype=data.y.dtype)
+        for i, cluster in enumerate(clusters):
+            for j, client in enumerate(cluster):
+                idx = sample_batch_idx(rng, data.x.shape[1], pcfg.E, pcfg.B)
+                np.take(data.x[client], idx, axis=0, out=xs[i, j])
+                np.take(data.y[client], idx, axis=0, out=ys[i, j])
+        key, keys = split_keys(key, clusters)
+        rounds.append((xs, ys, np.asarray(keys)))
+    return rounds, protocol_state_metadata(rng, key)
+
+
+@pytest.mark.parametrize("path",
+                         ["feeder", "inline", "block", "splitfed", "pool"])
+def test_device_gather_matches_host_reference(tiny_task, path, monkeypatch,
+                                              tmp_path):
+    """Every batched assembler — the feeder, in line, a round block,
+    SplitFed and a job pool whose lanes read different ClientData — hands
+    the round program exactly the batches the host ``np.take`` path built,
+    with the same keys, and leaves both randomness streams where that path
+    left them (the last round's checkpoint holds them)."""
+    from repro.checkpoint import load_checkpoint
+    from repro.core.engine import round_client_keys, splitfed_keys
+    from repro.core.jobs import JobSpec, run_job_pool
+
+    data, module = tiny_task
+    pcfg = ProtocolConfig(M=4, N=1, T=4, E=2, B=16, lr=0.05, seed=3,
+                          eval_every=4)
+    seen = []
+    for name in ("accept", "accept_block", "pool_accept_block"):
+        def entry(self, params, inputs, *rest, _orig=getattr(RoundRunner,
+                                                              name)):
+            seen.append(tuple(np.asarray(inputs[i]) for i in (0, 1, 3)))
+            return _orig(self, params, inputs, *rest)
+        monkeypatch.setattr(RoundRunner, name, entry)
+
+    def rounds_of(blocks):          # a leading block axis -> one per round
+        return [tuple(a[i] for a in b) for b in blocks
+                for i in range(b[0].shape[0])]
+
+    ck = str(tmp_path / "ck")
+    if path == "pool":
+        other = dataclasses.replace(data, x=data.x[::-1].copy(),
+                                    y=data.y[::-1].copy())
+        datas = [data, other]
+        run_job_pool([JobSpec(name=f"job{i}", module=module, data=d,
+                              pcfg=dataclasses.replace(pcfg, seed=i),
+                              checkpoint_path=f"{ck}{i}", checkpoint_every=4)
+                      for i, d in enumerate(datas)], block=2, prefetch=1)
+        cases = [(d, dataclasses.replace(pcfg, seed=i),
+                  rounds_of([tuple(a[i] for a in b) for b in seen]),
+                  f"{ck}{i}") for i, d in enumerate(datas)]
+    elif path == "splitfed":
+        run_splitfed(module, data, pcfg, engine="batched", prefetch=1)
+        cases = [(data, pcfg, seen, None)]
+    else:
+        run_pigeon(module, data, pcfg, engine="batched",
+                   prefetch=1 if path == "feeder" else 0,
+                   block=2 if path == "block" else 1,
+                   checkpoint_path=ck, checkpoint_every=4)
+        cases = [(data, pcfg, rounds_of(seen) if path == "block" else seen,
+                  ck)]
+    split = splitfed_keys if path == "splitfed" else round_client_keys
+    for d, p, got, ckpt in cases:
+        want, streams = _host_rounds(d, p, split)
+        assert len(got) == len(want) == p.T
+        for g, w in zip(got, want):
+            for a, b in zip(g, w):
+                np.testing.assert_array_equal(a, b)
+        if ckpt is not None:
+            _, meta = load_checkpoint(ckpt)
+            assert meta["round"] == p.T - 1
+            assert meta["rng_state"] == streams["rng_state"]
+            assert meta["key"] == streams["key"]
 
 
 # ---------------------------------------------------------------------------

@@ -444,14 +444,23 @@ def _batch_bytes(data, pcfg, rounds=1, jobs=1):
     return samples * (data.x[0, 0].nbytes + data.y[0, 0].nbytes)
 
 
+def _idx_bytes(pcfg, rounds=1, jobs=1):
+    """Bytes of the int32 (R, M_bar, E, B) index buffers of ``rounds``
+    rounds of ``jobs`` jobs."""
+    return 4 * jobs * rounds * pcfg.M * pcfg.E * pcfg.B
+
+
 @pytest.mark.parametrize("path", ["feeder", "inline", "block", "pool"])
 def test_transfer_bytes_on_put_and_eval(tiny_task, path):
-    """``assemble.put`` carries the bytes of the batches it copies
-    (``xs.nbytes + ys.nbytes``: one round, a K-round block or a J-lane pool
-    block), ``assemble.gather`` the same bytes written on the host, and
-    ``round.eval`` the test set's bytes."""
+    """``assemble.put`` carries the bytes of the indices it copies (one
+    round, a K-round block or a J-lane pool block), the device gather's
+    ``assemble.gather`` the bytes of the batches it writes, each round's
+    index draw an ``assemble.gather`` of its own, ``assemble.resident`` the
+    one put of the client shards per ClientData, and ``round.eval`` the
+    test set's bytes."""
     from repro.core.jobs import JobSpec, run_job_pool
-    data, module = tiny_task
+    tiny, module = tiny_task
+    data = dataclasses.replace(tiny)        # a ClientData with no copy yet
     pcfg = ProtocolConfig(M=4, N=1, T=4, E=2, B=16, lr=0.05, seed=0,
                           eval_every=2)
     mem = MemorySink()
@@ -467,22 +476,54 @@ def test_transfer_bytes_on_put_and_eval(tiny_task, path):
                    block=2 if path == "block" else 1, telemetry=tel)
     spans = mem.of("span")
     puts = [s for s in spans if s["name"] == "assemble.put"]
-    gathers = [s for s in spans if s["name"] == "assemble.gather"]
+    gathers = [s for s in spans if s["name"] == "assemble.gather"
+               and "device_bytes" in s]
+    draws = [s for s in spans if s["name"] == "assemble.gather"
+             and "device_bytes" not in s]
     evals = [s for s in spans if s["name"] == "round.eval"]
+    resident = [s for s in spans if s["name"] == "assemble.resident"]
     jobs = 2 if path == "pool" else 1
-    assert puts and evals
-    for s in puts:
-        k = s.get("k", 1)
-        assert s["h2d_bytes"] == _batch_bytes(data, pcfg, k, jobs)
-    assert sum(s["h2d_bytes"] for s in puts) == _batch_bytes(
+    assert puts and evals and len(puts) == len(gathers)
+    for put, gather in zip(puts, gathers):
+        k = put.get("k", 1)
+        assert put["h2d_bytes"] == _idx_bytes(pcfg, k, jobs)
+        assert gather["device_bytes"] == _batch_bytes(data, pcfg, k, jobs)
+    assert sum(s["h2d_bytes"] for s in puts) == _idx_bytes(pcfg, pcfg.T,
+                                                           jobs)
+    assert sum(s["device_bytes"] for s in gathers) == _batch_bytes(
         data, pcfg, pcfg.T, jobs)
-    assert {s["host_bytes"] for s in gathers} == {_batch_bytes(data, pcfg)}
-    assert len(gathers) == jobs * pcfg.T
+    assert len(draws) == jobs * pcfg.T
+    assert [s["h2d_bytes"] for s in resident] == [data.x.nbytes
+                                                   + data.y.nbytes]
     assert {s["h2d_bytes"] for s in evals} == {
         data.x_test.nbytes + data.y_test.nbytes}
-    # the sub-spans sit inside the assembly span of their thread
+    # the sub-spans sit inside the assembly span of their thread; the
+    # shards' put comes before the first round, outside any
     outer = ("feeder.assemble", "round.assemble")
-    assert all(s["path"].split("/")[0] in outer for s in puts + gathers)
+    assert all(s["path"].split("/")[0] in outer
+               for s in puts + gathers + draws)
+    assert resident[0]["path"] == "assemble.resident"
+
+
+def test_resident_copy_is_put_once_per_client_data(tiny_task, tiny_pcfg):
+    """A second run on the same ClientData gathers from the copy the first
+    one put; a different ClientData object gets a copy of its own."""
+    tiny, module = tiny_task
+    data = dataclasses.replace(tiny)
+
+    def resident_puts(d):
+        mem = MemorySink()
+        run_pigeon(module, d, tiny_pcfg, engine="batched", prefetch=1,
+                   telemetry=Telemetry(sinks=(mem,)))
+        return [s for s in mem.of("span") if s["name"] == "assemble.resident"]
+
+    assert len(resident_puts(data)) == 1
+    copy = data._resident[2:]
+    assert resident_puts(data) == []
+    assert all(a is b for a, b in zip(data._resident[2:], copy))
+    other = dataclasses.replace(data)
+    assert len(resident_puts(other)) == 1
+    assert other._resident[2] is not copy[0]
 
 
 def test_span_events_carry_their_start(tiny_task, tiny_pcfg):
