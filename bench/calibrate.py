@@ -41,10 +41,9 @@ def readings_for(cell, seed, kinds, check_rounds, drive_program=None):
     import gen
     import harness
     import reference
-    data = gen.make_data(cell.cfg, seed)
+    data = gen.make_data(cell.kind, cell.cfg, seed)
     jobs = gen.make_jobs(cell.cfg, cell.traffic, seed)
     every = int(cell.traffic["eval_every"])
-    n_test = data.x_test.shape[0]
     out = {}
     for kind in kinds:
         t0 = time.perf_counter()
@@ -53,13 +52,15 @@ def readings_for(cell, seed, kinds, check_rounds, drive_program=None):
         else:
             dtype = "bfloat16" if kind == "control" else "float32"
             fault = None if kind == "control" else kind
-            program = [reference.run(cell.cfg, data, job, check_rounds, every,
-                                     dtype=dtype, fault=fault)
+            program = [reference.run(cell.kind, cell.cfg, data, job,
+                                     check_rounds, every, dtype=dtype,
+                                     fault=fault)
                        for job in jobs]
-        refs = [reference.run(cell.cfg, data, job, check_rounds, every,
-                              follow=[r["selected"] for r in prog])
+        refs = [reference.run(cell.kind, cell.cfg, data, job, check_rounds,
+                              every, follow=[r["selected"] for r in prog])
                 for job, prog in zip(jobs, program)]
-        out[kind] = check.readings(program, refs, n_test)
+        out[kind] = check.readings(program, refs,
+                                   cell.kind.eval_units(data))
         out[kind]["seconds"] = time.perf_counter() - t0
     return out
 
@@ -85,7 +86,7 @@ def main() -> int:
     from repro.core import enable_compile_cache
     from repro.telemetry import Telemetry
     enable_compile_cache(str(harness.ROOT / ".jax-compile-cache"))
-    module = harness.build_module(cell.cfg)
+    module = cell.kind.build_module(cell.cfg)
     rounds = harness.warm_rounds(cell)
 
     def drive_program(data, jobs):

@@ -12,7 +12,8 @@ jobs:
 * ``cascade_diff``   rounds where the program's accepted flag or detection
                      count differs from the reference's (exact);
 * ``test_acc_gap``   |program - reference| test accuracy on the eval rounds
-                     among them, in images of the test set.
+                     among them, in the model kind's units of the test set
+                     (``kinds/<kind>.py::eval_units``: images for ``cnn``).
 
 The numbers a cell compares, and their limits, are the keys of its
 ``limits/<cell>.json``; the run is correct when each of them is at or below
@@ -31,10 +32,11 @@ def _rel(a: float, b: float) -> float:
 
 
 def readings(program: Sequence[Sequence[Dict]],
-             reference: Sequence[Sequence[Dict]], n_test: int
+             reference: Sequence[Sequence[Dict]], units: int
              ) -> Dict[str, float]:
     """Worst case of each number over jobs and rounds.  ``program[j]`` and
-    ``reference[j]`` are the records of job j, rounds aligned."""
+    ``reference[j]`` are the records of job j, rounds aligned; ``units`` is
+    what a test accuracy of 1 counts."""
     out = dict.fromkeys(NUMBERS, 0.0)
     for prog, ref in zip(program, reference):
         if len(prog) < len(ref):
@@ -52,7 +54,7 @@ def readings(program: Sequence[Sequence[Dict]],
                 out["cascade_diff"] += 1
             if "test_acc" in r and "test_acc" in p:
                 out["test_acc_gap"] = max(out["test_acc_gap"], round(
-                    abs(p["test_acc"] - r["test_acc"]) * n_test))
+                    abs(p["test_acc"] - r["test_acc"]) * units))
     return out
 
 

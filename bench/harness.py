@@ -4,13 +4,30 @@ reading of the per-layer metrics, and the comparison that decides
 
 A cell is an entry of ``BENCHMARK.json``'s ``workloads``.  Everything that
 belongs to it is found by name: its configuration ``configs/<config>.json``,
-its traffic ``traffic/<traffic>.json``, its limits ``limits/<cell>.json``
-and each per-layer metric's reader ``metrics/<metric>.py``.  A new cell is
-files and an entry; this module does not change.
+its model kind ``kinds/<model.kind>.py``, its traffic
+``traffic/<traffic>.json``, its limits ``limits/<cell>.json`` and each
+per-layer metric's reader ``metrics/<metric>.py``.  A new cell is files and
+an entry, and a new model kind is one more file; this module does not
+change.
+
+A model kind holds everything of the benchmark that depends on the model:
+
+* ``build_module(cfg)``: the program's ``SplitModule``, through its entry;
+* ``make_data(cfg, rng)``: a dict of the ``gen.Data`` fields, drawn from the
+  one ``numpy`` generator that ``gen.make_data`` seeds;
+* the reference's model, in plain ``jax.numpy``, importing nothing of the
+  program: ``init_params(key, model, dtype)`` giving (gamma, phi),
+  ``client_forward(gamma, x, model, prec)``,
+  ``ap_logits(phi, acts, model, prec)``, ``loss(logits, y)``,
+  ``count_correct(logits, y)`` and ``n_classes(model)``;
+* ``forward_flops(model)``: (client-side, whole-model) forward operations
+  per sample;
+* ``eval_units(data)``: what a test accuracy of 1 counts.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import math
@@ -40,6 +57,7 @@ class Cell:
     name: str
     chips: int
     cfg: Dict[str, Any]
+    kind: Any                 # the model kind's module (kinds/<kind>.py)
     traffic: Dict[str, Any]
     limits: Dict[str, float]
     end_to_end: List[Dict[str, Any]]
@@ -65,8 +83,9 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     traffic = json.loads((bench / "traffic" / f"{w['traffic']}.json")
                          .read_text())
     limits = json.loads((bench / "limits" / f"{name}.json").read_text())
-    return Cell(name=name, chips=int(w["chips"]), cfg=cfg, traffic=traffic,
-                limits=limits["limits"],
+    return Cell(name=name, chips=int(w["chips"]), cfg=cfg,
+                kind=load_kind(cfg["model"]["kind"], root),
+                traffic=traffic, limits=limits["limits"],
                 end_to_end=[m for m in spec["end_to_end"]
                             if _applies(m, name)],
                 per_layer=[m for m in spec["per_layer"] if _applies(m, name)])
@@ -184,20 +203,6 @@ def _round_clock_sink(pooled: bool, window: Optional[ProfileWindow] = None):
 # the system under test
 # ---------------------------------------------------------------------------
 
-def build_module(cfg: Dict[str, Any]):
-    from repro.core import from_cnn
-    from repro.models.cnn import CNNConfig
-    m = cfg["model"]
-    if m["kind"] != "cnn":
-        raise ValueError(f"unknown model kind {m['kind']!r}")
-    return from_cnn(CNNConfig(
-        name=cfg["name"], image_size=m["image_size"],
-        in_channels=m["in_channels"],
-        conv_channels=tuple(m["conv_channels"]), kernel=m["kernel"],
-        padding=m["padding"], fc_sizes=tuple(m["fc_sizes"]),
-        n_classes=m["n_classes"]))
-
-
 def _client_data(data):
     from repro.core import ClientData
     return ClientData(x=data.x, y=data.y, x0=data.x0, y0=data.y0,
@@ -314,13 +319,26 @@ class LayerContext:
                    and self.t0 < s["end"] <= self.t1)
 
 
-def load_reader(name: str) -> Callable[[LayerContext], Optional[float]]:
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
+@functools.lru_cache(maxsize=None)
+def _load_file(path: Path):
+    """The module in ``path``, loaded once per process: the reference's
+    programs take a model kind as a static argument, so one kind is one
+    object."""
+    name = f"bench_{path.parent.name}_{path.stem.replace('.', '_')}"
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(name: str) -> Callable[[LayerContext], Optional[float]]:
+    return _load_file(BENCH / "metrics" / f"{name}.py").read
+
+
+def load_kind(name: str, root: Path = ROOT):
+    """The model kind ``name``: the module ``bench/kinds/<name>.py`` under
+    ``root``."""
+    return _load_file((root / "bench" / "kinds" / f"{name}.py").resolve())
 
 
 # ---------------------------------------------------------------------------
@@ -365,9 +383,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     _log(f"device: {dev.platform} / {dev.device_kind} x {len(devices)}, "
          f"jax {jax.__version__}, compile cache {cache_dir}")
 
-    data = gen.make_data(cell.cfg, seed)
+    data = gen.make_data(cell.kind, cell.cfg, seed)
     jobs = gen.make_jobs(cell.cfg, cell.traffic, seed)
-    module = build_module(cell.cfg)
+    module = cell.kind.build_module(cell.cfg)
     cdata = _client_data(data)
     t_data = time.perf_counter()
 
@@ -492,8 +510,8 @@ def compare(cell: Cell, data, jobs, program) -> Dict[str, float]:
     program's selections, and the numbers of ``check.readings``."""
     import check
     import reference
-    refs = [reference.run(cell.cfg, data, job, CHECK_ROUNDS,
+    refs = [reference.run(cell.kind, cell.cfg, data, job, CHECK_ROUNDS,
                           int(cell.traffic["eval_every"]),
                           follow=[r["selected"] for r in prog])
             for job, prog in zip(jobs, program)]
-    return check.readings(program, refs, data.x_test.shape[0])
+    return check.readings(program, refs, cell.kind.eval_units(data))

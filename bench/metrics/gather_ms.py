@@ -1,6 +1,8 @@
-"""Host assembly (``core/engine.py::assemble_round_batches``): per driver
-round, the milliseconds of the numpy gather that writes the round's
-mini-batches into their host buffer (``assemble.gather``, on any thread)."""
+"""Host assembly (``core/engine.py::draw_round_idx`` and ``take_batches``):
+per driver round, the milliseconds of the ``assemble.gather`` spans, on any
+thread: the host's draw of the round's mini-batch indices, and the dispatch
+of the device gather that takes the mini-batches from the resident client
+data (not fenced, so the gather's own device time is not in it)."""
 
 
 def read(ctx):

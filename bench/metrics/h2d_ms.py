@@ -1,6 +1,6 @@
-"""Host assembly (``core/engine.py::put_batches``): per driver round, the
-milliseconds of the host-to-device copy of the stacked mini-batches, host
-relayout included, fenced until the copy is ready (``assemble.put``, on any
+"""Host assembly (``core/engine.py::put_idx``): per driver round, the
+milliseconds of the host-to-device copy of the round's drawn mini-batch
+indices, fenced until the copy is ready (``assemble.put``, on any
 thread)."""
 
 

@@ -1,15 +1,16 @@
 """The plain reference of a Pigeon-SL protocol run (Algorithm 1 of the
-paper, Section V-A's split CNNs), written from the paper's description in
-straightforward ``jax.numpy``.  It imports nothing of the program and takes
-nothing the program made: it draws the initial weights, the clusters, the
-mini-batch indices and the per-step keys from the job's seed itself.
+paper), written from the paper's description in straightforward
+``jax.numpy``.  It imports nothing of the program and takes nothing the
+program made: it draws the initial weights, the clusters, the mini-batch
+indices and the per-step keys from the job's seed itself.  The model is the
+configuration's kind (``kinds/<kind>.py``): its weights, its two forward
+halves, its loss and its count of correct answers; this module holds the
+protocol around it.
 
 The draws follow the order the protocol defines for a seeded run:
 
-* ``key = PRNGKey(seed)``; ``key, k0 = split(key)``; the CNN is initialised
-  from ``k0`` (conv kernels truncated normal in [-2, 2] over
-  ``sqrt(k*k*c_in)``, dense kernels truncated normal times
-  ``1/sqrt(d_in)``, zero biases);
+* ``key = PRNGKey(seed)``; ``key, k0 = split(key)``; the model is
+  initialised from ``k0`` (``kind.init_params``);
 * per round, from ``numpy.random.default_rng(seed)``: a permutation of the M
   clients cut into R equal clusters (each sorted), then every client's
   (E, B) batch indices, cluster by cluster;
@@ -25,12 +26,13 @@ its loss on D_o, takes the argmin, and re-checks the winner's hand-off
 against its validation activations.
 
 ``dtype="float32"`` runs at ``Precision.HIGHEST`` (the reference);
-``dtype="bfloat16"`` keeps weights, data and activations in bfloat16 (the
-control, one precision below the configuration's).  ``fault`` plants one of
-the faults the check must catch, for reading its numbers: ``unchanged``
-(theta never committed), ``half_batch`` (half of every mini-batch left
-out), ``altered`` (the first cluster's validation loss raised 1% where it
-is produced), ``wrong_pick`` (the worst candidate selected).
+``dtype="bfloat16"`` keeps weights, floating inputs and activations in
+bfloat16 (the control, one precision below the configuration's).  ``fault``
+plants one of the faults the check must catch, for reading its numbers:
+``unchanged`` (theta never committed), ``half_batch`` (half of every
+mini-batch left out), ``altered`` (the first cluster's validation loss
+raised 1% where it is produced), ``wrong_pick`` (the worst candidate
+selected).
 
 ``follow`` makes the reference take the program's selected cluster at each
 round, so that the rounds after a near-tie are compared on one trajectory;
@@ -39,7 +41,7 @@ far the chosen candidate's reference loss lies above the reference's best.
 """
 from __future__ import annotations
 
-import math
+import json
 from functools import partial
 from typing import Dict, List, Optional, Sequence
 
@@ -48,93 +50,31 @@ import jax.numpy as jnp
 import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
-LABEL_SHIFT = 3        # label_flip: y -> (y + 3) mod 10 (the paper's attack)
+LABEL_SHIFT = 3        # label_flip: y -> (y + 3) mod n_classes (the paper's)
 ACT_KEEP = 0.1         # activation: keep 10% of the true cut activation
 GRAD_SCALE = -1.0      # gradient: reverse the received cut gradient
 FAULTS = (None, "unchanged", "half_batch", "altered", "wrong_pick")
 
 
-def init_params(key, model: Dict, dtype):
-    kk, pad = model["kernel"], model["padding"]
-    convs_c, fc = model["conv_channels"], list(model["fc_sizes"])
-    n_conv = len(convs_c)
-    keys = jax.random.split(key, n_conv + len(fc) + 1)
-    convs, c_in, s = [], model["in_channels"], model["image_size"]
-    for i, c_out in enumerate(convs_c):
-        w = jax.random.truncated_normal(keys[i], -2, 2, (kk, kk, c_in, c_out))
-        w = (w / math.sqrt(kk * kk * c_in)).astype(jnp.float32)
-        convs.append((w, jnp.zeros((c_out,), jnp.float32)))
-        s = (s + 2 * pad - kk + 1) // 2
-        c_in = c_out
-    flat = s * s * c_in
-
-    def dense(k, d_in, d_out):
-        w = jax.random.truncated_normal(k, -2.0, 2.0, (d_in, d_out))
-        return ((w * (1.0 / math.sqrt(d_in))).astype(jnp.float32),
-                jnp.zeros((d_out,), jnp.float32))
-
-    gamma = (tuple(convs), dense(keys[n_conv], flat, fc[0]))
-    dims = fc + [model["n_classes"]]
-    phi = tuple(dense(keys[n_conv + 1 + j], dims[j], dims[j + 1])
-                for j in range(len(dims) - 1))
-    return jax.tree.map(lambda a: a.astype(dtype), (gamma, phi))
-
-
-def conv2d(x, w, pad: int, prec):
-    """Stride-1 NHWC convolution with an HWIO kernel, as one matrix product
-    over the k*k shifted copies of the zero-padded input."""
-    k = w.shape[0]
-    b, h, wd, c = x.shape
-    xp = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
-    ho, wo = h + 2 * pad - k + 1, wd + 2 * pad - k + 1
-    cols = [xp[:, dy:dy + ho, dx:dx + wo, :]
-            for dy in range(k) for dx in range(k)]
-    patches = jnp.stack(cols, axis=3).reshape(b, ho, wo, k * k * c)
-    return jnp.dot(patches, w.reshape(k * k * c, -1), precision=prec)
-
-
-def client_forward(gamma, x, model: Dict, prec):
-    convs, (w, b) = gamma
-    pad = model["padding"]
-    for cw, cb in convs:
-        y = jnp.maximum(conv2d(x, cw, pad, prec) + cb, 0)
-        x = jax.lax.reduce_window(y, -jnp.inf, jax.lax.max,
-                                  (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
-    x = x.reshape(x.shape[0], -1)
-    return jnp.maximum(jnp.dot(x, w, precision=prec) + b, 0)
-
-
-def ap_forward(phi, a, prec):
-    for i, (w, b) in enumerate(phi):
-        a = jnp.dot(a, w, precision=prec) + b
-        if i < len(phi) - 1:
-            a = jnp.maximum(a, 0)
-    return a
-
-
-def xent(logits, y):
-    logits = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    return jnp.mean(lse - jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0])
-
-
 def noise_blend(acts, key, keep):
     """Keep ``keep`` of the activation; fill the rest with Gaussian noise
-    scaled to each sample's norm."""
+    scaled to each sample's norm, taken over every axis but the first."""
     keep = jnp.float32(keep)
     n = jax.random.normal(key, acts.shape, jnp.float32)
     a32 = acts.astype(jnp.float32)
-    g = jnp.sqrt(jnp.sum(a32 * a32, axis=1, keepdims=True))
-    nn = jnp.sqrt(jnp.sum(n * n, axis=1, keepdims=True))
+    axes = tuple(range(1, acts.ndim))
+    g = jnp.sqrt(jnp.sum(a32 * a32, axis=axes, keepdims=True))
+    nn = jnp.sqrt(jnp.sum(n * n, axis=axes, keepdims=True))
     out = keep * a32 + (1.0 - keep) * (n * (g / jnp.maximum(nn, 1e-12)))
     return out.astype(acts.dtype)
 
 
-@partial(jax.jit, static_argnames=("model_key", "attack", "prec", "half"))
-def _client_turn(gamma, phi, xs, ys, key, lr, *, model_key, attack, prec,
-                 half):
-    model = dict(model_key)
-    n_classes = model["n_classes"]
+@partial(jax.jit, static_argnames=("kind", "model_key", "attack", "prec",
+                                   "half"))
+def _client_turn(gamma, phi, xs, ys, key, lr, *, kind, model_key, attack,
+                 prec, half):
+    model = json.loads(model_key)
+    n_classes = kind.n_classes(model)
 
     def step(carry, batch):
         g, p, k = carry
@@ -144,11 +84,13 @@ def _client_turn(gamma, phi, xs, ys, key, lr, *, model_key, attack, prec,
         k, s = jax.random.split(k)
         k_act, _ = jax.random.split(s)
         y_sent = (y + LABEL_SHIFT) % n_classes if attack == "label_flip" else y
-        acts, vjp = jax.vjp(lambda gg: client_forward(gg, x, model, prec), g)
+        acts, vjp = jax.vjp(
+            lambda gg: kind.client_forward(gg, x, model, prec), g)
         sent = noise_blend(acts, k_act, ACT_KEEP) if attack == "activation" \
             else acts
         loss, (g_phi, g_acts) = jax.value_and_grad(
-            lambda pp, aa: xent(ap_forward(pp, aa, prec), y_sent),
+            lambda pp, aa: kind.loss(kind.ap_logits(pp, aa, model, prec),
+                                     y_sent),
             argnums=(0, 1))(p, sent)
         if attack == "gradient":
             g_acts = (GRAD_SCALE * g_acts.astype(jnp.float32)).astype(
@@ -162,44 +104,48 @@ def _client_turn(gamma, phi, xs, ys, key, lr, *, model_key, attack, prec,
     return g, p, jnp.mean(losses)
 
 
-@partial(jax.jit, static_argnames=("model_key", "prec"))
-def _validate(gamma, phi, x0, y0, *, model_key, prec):
-    model = dict(model_key)
-    acts = client_forward(gamma, x0, model, prec)
-    return xent(ap_forward(phi, acts, prec), y0), acts
+@partial(jax.jit, static_argnames=("kind", "model_key", "prec"))
+def _validate(gamma, phi, x0, y0, *, kind, model_key, prec):
+    model = json.loads(model_key)
+    acts = kind.client_forward(gamma, x0, model, prec)
+    return kind.loss(kind.ap_logits(phi, acts, model, prec), y0), acts
 
 
-@partial(jax.jit, static_argnames=("model_key", "prec"))
-def _recheck(gamma, x0, ref_acts, *, model_key, prec):
+@partial(jax.jit, static_argnames=("kind", "model_key", "prec"))
+def _recheck(gamma, x0, ref_acts, *, kind, model_key, prec):
     """The next round's first clients re-send g(x0, gamma received); the AP
     compares with the winner's validation activations."""
-    recv = client_forward(gamma, x0, dict(model_key), prec).astype(jnp.float32)
+    recv = kind.client_forward(gamma, x0, json.loads(model_key),
+                               prec).astype(jnp.float32)
     ref = ref_acts.astype(jnp.float32)
     return jnp.linalg.norm(recv - ref) / jnp.maximum(jnp.linalg.norm(ref),
                                                      1e-12)
 
 
-@partial(jax.jit, static_argnames=("model_key", "prec"))
-def _count_correct(gamma, phi, x, y, *, model_key, prec):
-    model = dict(model_key)
-    logits = ap_forward(phi, client_forward(gamma, x, model, prec), prec)
-    return jnp.sum(jnp.argmax(logits, axis=-1) == y)
+@partial(jax.jit, static_argnames=("kind", "model_key", "prec"))
+def _count_correct(gamma, phi, x, y, *, kind, model_key, prec):
+    model = json.loads(model_key)
+    acts = kind.client_forward(gamma, x, model, prec)
+    return kind.count_correct(kind.ap_logits(phi, acts, model, prec), y)
 
 
-def _model_key(model: Dict):
-    return tuple((k, tuple(v) if isinstance(v, list) else v)
-                 for k, v in sorted(model.items()))
+def _inputs(a: np.ndarray, dt):
+    """A device copy of model inputs: floating ones in the run's dtype,
+    integer ones (labels, token ids) as they are."""
+    return jnp.asarray(a, dt) if np.issubdtype(a.dtype, np.floating) \
+        else jnp.asarray(a)
 
 
-def run(cfg: Dict, data, job, rounds: int, eval_every: int, *,
+def run(kind, cfg: Dict, data, job, rounds: int, eval_every: int, *,
         dtype: str = "float32", fault: Optional[str] = None,
         follow: Optional[Sequence[int]] = None) -> List[Dict]:
-    """The first ``rounds`` rounds of one job: a list of records with
-    ``val_losses``, ``train_losses``, ``selected``, ``accepted``,
-    ``detections``, ``select_excess`` and, on eval rounds, ``test_acc``."""
+    """The first ``rounds`` rounds of one job of a configuration whose model
+    is of ``kind``: a list of records with ``val_losses``, ``train_losses``,
+    ``selected``, ``accepted``, ``detections``, ``select_excess`` and, on
+    eval rounds, ``test_acc`` (correct answers over ``kind.eval_units``)."""
     assert fault in FAULTS, fault
     model = cfg["model"]
-    mk = _model_key(model)
+    mk = json.dumps(model, sort_keys=True)
     dt = jnp.float32 if dtype == "float32" else jnp.bfloat16
     prec = HIGHEST if dtype == "float32" else None
     m, r = cfg["M"], cfg["N"] + 1
@@ -208,8 +154,8 @@ def run(cfg: Dict, data, job, rounds: int, eval_every: int, *,
     rng = np.random.default_rng(job.seed)
     key = jax.random.PRNGKey(job.seed)
     key, k0 = jax.random.split(key)
-    theta = init_params(k0, model, dt)
-    x0, y0 = jnp.asarray(data.x0, dt), jnp.asarray(data.y0)
+    theta = kind.init_params(k0, model, dt)
+    x0, y0 = _inputs(data.x0, dt), jnp.asarray(data.y0)
     out = []
     for t in range(rounds):
         perm = rng.permutation(m)
@@ -230,15 +176,16 @@ def run(cfg: Dict, data, job, rounds: int, eval_every: int, *,
             g, p = theta
             losses = []
             for j, c in enumerate(cl):
-                xs = jnp.asarray(data.x[c][idx[i][j]], dt)
+                xs = _inputs(data.x[c][idx[i][j]], dt)
                 ys = jnp.asarray(data.y[c][idx[i][j]])
-                kind = job.attack if c in job.malicious else "none"
+                attack = job.attack if c in job.malicious else "none"
                 g, p, loss = _client_turn(g, p, xs, ys, keys[i][j], lr,
-                                          model_key=mk, attack=kind,
-                                          prec=prec,
+                                          kind=kind, model_key=mk,
+                                          attack=attack, prec=prec,
                                           half=fault == "half_batch")
                 losses.append(float(loss))
-            vl, acts = _validate(g, p, x0, y0, model_key=mk, prec=prec)
+            vl, acts = _validate(g, p, x0, y0, kind=kind, model_key=mk,
+                                 prec=prec)
             cands.append((g, p, acts))
             vls.append(float(vl))
             tls.append(float(np.mean(losses)))
@@ -250,7 +197,8 @@ def run(cfg: Dict, data, job, rounds: int, eval_every: int, *,
             sel = int(np.argmax(vls))
         excess = (vls[sel] - vls[best]) / abs(vls[best])
         g, p, acts = cands[sel]
-        dist = float(_recheck(g, x0, acts, model_key=mk, prec=prec))
+        dist = float(_recheck(g, x0, acts, kind=kind, model_key=mk,
+                              prec=prec))
         accepted = dist <= cfg["tamper_tol"]
         if accepted and fault != "unchanged":
             theta = (g, p)
@@ -260,10 +208,11 @@ def run(cfg: Dict, data, job, rounds: int, eval_every: int, *,
         if t % eval_every == 0:
             correct = 0
             for s0 in range(0, data.x_test.shape[0], cfg["eval_batch"]):
-                xb = jnp.asarray(data.x_test[s0:s0 + cfg["eval_batch"]], dt)
+                xb = _inputs(data.x_test[s0:s0 + cfg["eval_batch"]], dt)
                 yb = jnp.asarray(data.y_test[s0:s0 + cfg["eval_batch"]])
                 correct += int(_count_correct(theta[0], theta[1], xb, yb,
-                                              model_key=mk, prec=prec))
-            rec["test_acc"] = correct / data.x_test.shape[0]
+                                              kind=kind, model_key=mk,
+                                              prec=prec))
+            rec["test_acc"] = correct / kind.eval_units(data)
         out.append(rec)
     return out

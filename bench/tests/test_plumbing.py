@@ -4,11 +4,12 @@ compile in it, the metrics, and the check against the reference."""
 import json
 import subprocess
 import sys
+import time
 
 import pytest
 
 import _paths
-from tiny import OPEN_CELLS, run_tiny
+from tiny import OPEN_CELLS, run_tiny, shrink
 
 import harness
 
@@ -52,8 +53,10 @@ def test_traced_run_reads_the_span_metrics():
 def test_a_new_cell_is_files_and_an_entry(tmp_path):
     root = tmp_path
     bench = root / "bench"
-    for sub in ("configs", "traffic", "limits"):
+    for sub in ("kinds", "configs", "traffic", "limits"):
         (bench / sub).mkdir(parents=True)
+    (bench / "kinds" / "cnn.py").write_text(
+        (_paths.BENCH / "kinds" / "cnn.py").read_text())
     spec = json.loads(json.dumps(SPEC))
     spec["workloads"].append({"name": "mnist_t2.honest", "config": "mnist_t2",
                               "traffic": "honest", "chips": 1, "why": "test"})
@@ -70,6 +73,41 @@ def test_a_new_cell_is_files_and_an_entry(tmp_path):
     assert cell.traffic["jobs"][0]["attack"] == "none"
     assert [m["name"] for m in cell.end_to_end] == ["rounds_per_s",
                                                     "setup_s"]
+
+
+def test_a_new_kind_is_one_file(tmp_path):
+    """A model kind that exists only in a temporary root, with its config,
+    traffic, limits and entry there, runs a tiny cell end to end, with no
+    edit to the benchmark's own files."""
+    root = tmp_path
+    bench = root / "bench"
+    for sub in ("kinds", "configs", "traffic", "limits"):
+        (bench / sub).mkdir(parents=True)
+    (bench / "kinds" / "cnn_copy.py").write_text(
+        (_paths.BENCH / "kinds" / "cnn.py").read_text())
+    cfg = json.loads((_paths.BENCH / "configs" / "mnist_t2.json").read_text())
+    cfg["model"]["kind"] = "cnn_copy"
+    (bench / "configs" / "mnist_copy.json").write_text(json.dumps(cfg))
+    (bench / "traffic" / "flip.json").write_text(
+        (_paths.BENCH / "traffic" / "paper_label_flip.json").read_text())
+    (bench / "limits" / "mnist_copy.flip.json").write_text(
+        (_paths.BENCH / "limits" / "mnist_t2.paper.json").read_text())
+    spec = json.loads(json.dumps(SPEC))
+    spec["workloads"].append({"name": "mnist_copy.flip",
+                              "config": "mnist_copy", "traffic": "flip",
+                              "chips": 1, "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+
+    cell = shrink(harness.load_cell("mnist_copy.flip", root=root))
+    assert cell.kind.__file__ == str(
+        (bench / "kinds" / "cnn_copy.py").resolve())
+    assert cell.kind is not harness.load_kind("cnn")
+    seed = 2**31 + 11
+    res = harness.run_cell(cell, seed, 1.0, False,
+                           t_process=time.perf_counter(), require_chip=False)
+    assert res["correct"], res["checks"]
+    assert res["checks"]["compiles_in_window"]["value"] == 0
+    assert res["checks"] == run_tiny("mnist_t2.paper", seed)["checks"]
 
 
 def test_no_tpu_exits_before_any_phase():

@@ -27,13 +27,17 @@ def _load(name: str):
     return cell
 
 
-def tiny_cell(name: str, lr=None):
-    cell = _load(name)
+def shrink(cell, lr=None):
+    """The cell at the tiny size, in place."""
     r = cell.cfg["N"] + 1
     cell.cfg.update(M=2 * r, E=3, B=16, d_m=80, D_o=60, n_test=100)
     if lr is not None:
         cell.cfg["lr"] = lr
     return cell
+
+
+def tiny_cell(name: str, lr=None):
+    return shrink(_load(name), lr)
 
 
 def run_tiny(name: str, seed: int = 2**31 + 11, trace: bool = False,
