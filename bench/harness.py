@@ -22,7 +22,11 @@ A model kind holds everything of the benchmark that depends on the model:
   ``count_correct(logits, y)`` and ``n_classes(model)``;
 * ``forward_flops(model)``: (client-side, whole-model) forward operations
   per sample;
-* ``eval_units(data)``: what a test accuracy of 1 counts.
+* ``eval_units(data)``: what a test accuracy of 1 counts;
+* ``tiny_model(model)``: the configuration's ``model`` block cut to a size
+  at which the CPU runs a tiny round in seconds, keeping every kind of
+  layer of the model, in its pattern.  Only the benchmark's CPU tests call
+  it (``tests/tiny.py::shrink``); a run on the chip never does.
 """
 from __future__ import annotations
 

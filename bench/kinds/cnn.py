@@ -46,6 +46,12 @@ def build_module(cfg: Dict):
         n_classes=m["n_classes"]))
 
 
+def tiny_model(model: Dict) -> Dict:
+    """The model block for the CPU tests' tiny cells: the paper's CNNs run
+    a tiny round in seconds at their published widths, so unchanged."""
+    return model
+
+
 # ---------------------------------------------------------------------------
 # data
 # ---------------------------------------------------------------------------
