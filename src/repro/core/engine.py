@@ -277,7 +277,8 @@ def train_round_batched(module: SplitModule, theta, clusters, data: ClientData,
             key, prefetched = assemble_round(rng, key, data, clusters, pcfg,
                                              tm, t, telemetry=tel)
     xs, ys, avec, keys = prefetched
-    with tel.span("round.step", round=t) as sp:
+    with tel.span("round.step", round=t,
+                  patch_dw_layers=module.patch_dw_layers) as sp:
         (gs, ps), aux, vlosses, vacts = protocol_runner(
             module, pcfg.lr, placement, with_stats,
             quant=pcfg.comm.quant).candidates(
@@ -333,7 +334,8 @@ def pigeon_round_accept(module: SplitModule, theta, clusters, data: ClientData,
     runner = protocol_accept_runner(module, pcfg.lr, placement, policy,
                                     pcfg.tamper_check, pcfg.tamper_tol,
                                     quant=pcfg.comm.quant)
-    with tel.span("round.step", round=t) as sp:
+    with tel.span("round.step", round=t,
+                  patch_dw_layers=module.patch_dw_layers) as sp:
         theta_next, fetch = runner.accept(theta, prefetched, (x0, y0))
         # fence the fetch only: the step span absorbs the device round
         # (block_until_ready waits, it does not transfer), leaving the fetch
@@ -527,7 +529,8 @@ def splitfed_round_batched(module: SplitModule, theta, clusters, data: ClientDat
                                                       clusters, pcfg, tm, t,
                                                       telemetry=tel)
     xs, ys, avec, keys = prefetched
-    with tel.span("round.step", round=t) as sp:
+    with tel.span("round.step", round=t,
+                  patch_dw_layers=module.patch_dw_layers) as sp:
         (g_avg, p_avg), aux, vlosses, _ = splitfed_runner(
             module, pcfg.lr, placement, with_stats,
             quant=pcfg.comm.quant).candidates(
@@ -565,7 +568,8 @@ def splitfed_round_accept(module: SplitModule, theta, clusters,
                                                       telemetry=tel)
     runner = splitfed_accept_runner(module, pcfg.lr, placement, policy,
                                     quant=pcfg.comm.quant)
-    with tel.span("round.step", round=t) as sp:
+    with tel.span("round.step", round=t,
+                  patch_dw_layers=module.patch_dw_layers) as sp:
         theta_next, fetch = runner.accept(theta, prefetched, (x0, y0))
         sp.fence(fetch)
     with tel.span("round.fetch", round=t):
@@ -689,7 +693,8 @@ def pigeon_block_accept(module: SplitModule, theta, clusters_k,
                                     pcfg.tamper_check, pcfg.tamper_tol,
                                     quant=pcfg.comm.quant)
     k = len(clusters_k)
-    with tel.span("block.step", round=t0, k=k) as sp:
+    with tel.span("block.step", round=t0, k=k,
+                  patch_dw_layers=module.patch_dw_layers) as sp:
         theta_next, fetches = runner.accept_block(theta, block_inputs,
                                                   (x0, y0))
         sp.fence(fetches)
@@ -718,7 +723,8 @@ def splitfed_block_accept(module: SplitModule, theta, clusters_k,
     runner = splitfed_accept_runner(module, pcfg.lr, placement, policy,
                                     quant=pcfg.comm.quant)
     k = len(clusters_k)
-    with tel.span("block.step", round=t0, k=k) as sp:
+    with tel.span("block.step", round=t0, k=k,
+                  patch_dw_layers=module.patch_dw_layers) as sp:
         theta_next, fetches = runner.accept_block(theta, block_inputs,
                                                   (x0, y0))
         sp.fence(fetches)
@@ -888,7 +894,8 @@ def run_pigeon_sweep(module: SplitModule, data: ClientData, pcfg: ProtocolConfig
                                          jnp.stack(key_rows)))
                         clusters_sk.append(clusters_s)
                     block_inputs = stack_payloads(payloads)
-                with tel.span("block.step", round=t0, k=k) as sp:
+                with tel.span("block.step", round=t0, k=k,
+                              patch_dw_layers=module.patch_dw_layers) as sp:
                     thetas, (vl_k, tl_k, sels_k) = runner.sweep_block(
                         thetas, block_inputs, (x0, y0))
                     sp.fence(sels_k)
@@ -963,7 +970,8 @@ def run_pigeon_sweep(module: SplitModule, data: ClientData, pcfg: ProtocolConfig
                     key_rows.append(krow)
                     avecs.append(avec_i)
                 avec = jax.tree.map(lambda *ls: jnp.stack(ls), *avecs)
-            with tel.span("round.step", round=t) as sp:
+            with tel.span("round.step", round=t,
+                          patch_dw_layers=module.patch_dw_layers) as sp:
                 thetas, aux, vlosses, sels = sweep_round(
                     module, pcfg.lr, thetas,
                     (jnp.stack(xs), jnp.stack(ys), avec,

@@ -399,6 +399,7 @@ def _run_bucket(states: List[_JobState], order: List[int], block: int,
     plans = plan_pool(states, order, n_lanes, block)
 
     st0 = states[runnable[0]]
+    patch_dw_layers = st0.spec.module.patch_dw_layers
     runner = protocol_accept_runner(
         st0.spec.module, st0.pcfg.lr, placement, st0.policy,
         st0.pcfg.tamper_check, st0.pcfg.tamper_tol,
@@ -496,7 +497,8 @@ def _run_bucket(states: List[_JobState], order: List[int], block: int,
                 active_j = jnp.asarray([j >= 0 for j in plan.assign])
                 prev_assign = plan.assign
             with tel.span("pool.step", block=b, k=plan.k,
-                          active=int(np.sum([j >= 0 for j in plan.assign]))) as sp:
+                          active=int(np.sum([j >= 0 for j in plan.assign])),
+                          patch_dw_layers=patch_dw_layers) as sp:
                 theta_j, fetches = runner.pool_accept_block(
                     theta_j, binputs, val_j, active_j)
                 sp.fence(fetches)

@@ -396,7 +396,8 @@ def _train_round(module: SplitModule, theta, clusters, data: ClientData,
                                    placement=placement, prefetched=prefetched,
                                    with_stats=with_stats, telemetry=tel)
     results = []
-    with tel.span("round.step", round=t):
+    with tel.span("round.step", round=t,
+                  patch_dw_layers=module.patch_dw_layers):
         for cluster in clusters:
             key, sub = jax.random.split(key)
             out = train_cluster(module, theta[0], theta[1], cluster, data,
@@ -883,7 +884,8 @@ def run_vanilla_sl(module: SplitModule, data: ClientData, pcfg: ProtocolConfig,
             meter = CommMeter()
             order = rng.permutation(pcfg.M).tolist()
             key, sub = jax.random.split(key)
-            with tel.span("round.step", round=t):
+            with tel.span("round.step", round=t,
+                          patch_dw_layers=module.patch_dw_layers):
                 gamma, phi, train_loss = train_cluster(
                     module, gamma, phi, order, data, pcfg, tm, t, rng, sub,
                     meter, d_c)
@@ -1069,7 +1071,8 @@ def run_splitfed(module: SplitModule, data: ClientData, pcfg: ProtocolConfig,
                         with_stats=policy.needs_message_stats, telemetry=tel)
                 else:
                     results = []
-                    with tel.span("round.step", round=t):
+                    with tel.span("round.step", round=t,
+                                  patch_dw_layers=module.patch_dw_layers):
                         for cluster in clusters:
                             gs, ps, sts = [], [], []
                             for client in cluster:

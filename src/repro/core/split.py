@@ -40,6 +40,10 @@ class SplitModule:
     ap_loss: Callable[[Pytree, jnp.ndarray, jnp.ndarray], jnp.ndarray]
     predict: Callable[[Pytree, Pytree, jnp.ndarray], jnp.ndarray]
     n_classes: int = 10
+    # conv layers whose filter gradient comes from flat patch rows
+    # (``models/cnn.py::takes_patch_filter_grad``); the round-step spans
+    # carry it as their ``patch_dw_layers`` attr
+    patch_dw_layers: int = 0
 
     def loss(self, gamma, phi, x, y):
         return self.ap_loss(phi, self.client_forward(gamma, x), y)
@@ -54,6 +58,7 @@ def from_cnn(cfg) -> SplitModule:
         ap_loss=lambda p, a, y: _xent(cnn_mod.cnn_ap_forward(p, cfg, a), y),
         predict=lambda g, p, x: cnn_mod.cnn_predict(g, p, cfg, x),
         n_classes=cfg.n_classes,
+        patch_dw_layers=cnn_mod.patch_filter_grad_layers(cfg),
     )
 
 

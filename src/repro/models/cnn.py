@@ -13,6 +13,7 @@ consumes it.  2x2 max-pooling after each conv keeps the FC sizes manageable
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, List, Sequence, Tuple
 
@@ -58,12 +59,94 @@ def _conv_init(key, k: int, c_in: int, c_out: int) -> Params:
     return {"w": w.astype(jnp.float32), "b": jnp.zeros((c_out,), jnp.float32)}
 
 
-def _conv(p: Params, x: jnp.ndarray, padding: int) -> jnp.ndarray:
-    y = jax.lax.conv_general_dilated(
-        x, p["w"], window_strides=(1, 1),
+_DIMS = ("NHWC", "HWIO", "NHWC")
+
+
+def _conv_xla(x: jnp.ndarray, w: jnp.ndarray, padding: int) -> jnp.ndarray:
+    return jax.lax.conv_general_dilated(
+        x, w, window_strides=(1, 1),
         padding=[(padding, padding), (padding, padding)],
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    return y + p["b"]
+        dimension_numbers=_DIMS)
+
+
+def takes_patch_filter_grad(k: int, c_in: int, c_out: int) -> bool:
+    """Whether a k x k conv from ``c_in`` to ``c_out`` channels takes its
+    filter gradient from flat patch rows (:func:`_patch_filter_grad`).
+
+    XLA's own filter gradient, vmapped over clusters, makes the whole image
+    the convolution window and the batch its contraction feature.  On a
+    TPU v5e, for MNIST's conv1 (25 x 1 x 2 weights) that took 326 us a
+    step against 58 us from flat patch rows; for MNIST's conv2 (200
+    weights) and the CIFAR-10 convs (864 and more) XLA's took 31 us and
+    69 us against 62 us and 767 us (``benchmarks/conv_filter_grad.py``).
+    The flat rows cost one product per weight and pixel on the vector
+    unit, so they pay only for a filter of few weights."""
+    return k * k * c_in * c_out <= 64
+
+
+def _patch_filter_grad(x: jnp.ndarray, dy: jnp.ndarray, k: int,
+                       padding: int) -> jnp.ndarray:
+    """dw[i, j, c, o] = sum over b, h, w of xpad[b, h+i, w+j, c] dy[b, h, w, o]
+    for a stride-1 conv, as float32 products summed on the vector unit.
+
+    Each zero-padded image is flattened to rows of pitch wq = W + 2*padding,
+    so tap (i, j) of every output pixel (h, w) is flat element
+    (h + i)*wq + w + j: one contiguous slice of length Ho*wq per tap, with
+    B and the flat pixels on the TPU's sublanes and lanes.  dy is padded
+    with zero columns to the same pitch, so the slice's wrapped-around
+    columns add nothing."""
+    b, _, w, c = x.shape
+    _, ho, wo, o = dy.shape
+    wq = w + 2 * padding
+    n = ho * wq
+    # one more zero row keeps the last tap's slice inside the flat image
+    xf = jnp.pad(x, ((0, 0), (padding, padding + 1), (padding, padding),
+                     (0, 0)))
+    xf = xf.transpose(3, 0, 1, 2).reshape(c, b, -1)
+    dyf = jnp.pad(dy, ((0, 0), (0, 0), (0, wq - wo), (0, 0)))
+    dyf = dyf.transpose(3, 0, 1, 2).reshape(o, b, n)
+    taps = jnp.stack([xf[:, :, i * wq + j:i * wq + j + n]
+                      for i in range(k) for j in range(k)])
+    dw = jnp.sum(taps[:, :, None] * dyf[None, None], axis=(3, 4))
+    return dw.reshape(k, k, c, o)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _conv_patch_dw(x: jnp.ndarray, w: jnp.ndarray,
+                   padding: int) -> jnp.ndarray:
+    """The same convolution as :func:`_conv_xla`, forward and input gradient
+    alike; only its filter gradient is :func:`_patch_filter_grad`."""
+    return _conv_xla(x, w, padding)
+
+
+def _conv_patch_dw_fwd(x, w, padding):
+    return _conv_xla(x, w, padding), (x, w)
+
+
+def _conv_patch_dw_bwd(padding, res, dy):
+    x, w = res
+    _, vjp_x = jax.vjp(lambda x_: _conv_xla(x_, w, padding), x)
+    (dx,) = vjp_x(dy)
+    with jax.named_scope("conv_filter_grad"):
+        dw = _patch_filter_grad(x, dy, w.shape[0], padding)
+    return dx, dw
+
+
+_conv_patch_dw.defvjp(_conv_patch_dw_fwd, _conv_patch_dw_bwd)
+
+
+def _conv(p: Params, x: jnp.ndarray, padding: int) -> jnp.ndarray:
+    k, _, c_in, c_out = p["w"].shape
+    conv = (_conv_patch_dw if takes_patch_filter_grad(k, c_in, c_out)
+            else _conv_xla)
+    return conv(x, p["w"], padding) + p["b"]
+
+
+def patch_filter_grad_layers(cfg: CNNConfig) -> int:
+    """How many of ``cfg``'s conv layers take the patch filter gradient."""
+    chans = (cfg.in_channels,) + tuple(cfg.conv_channels)
+    return sum(takes_patch_filter_grad(cfg.kernel, c_in, c_out)
+               for c_in, c_out in zip(chans, chans[1:]))
 
 
 def _maxpool2(x: jnp.ndarray) -> jnp.ndarray:

@@ -139,3 +139,10 @@ def test_accept_program_compiles_for_v5e(tpu_compile):
     txt = tpu_compile(runner.audit_body("accept"), theta,
                       (xs, ys, avec, keys), val)
     assert _kernel_names(txt) == {"tamper_distance"}
+    # conv1's filter gradient comes from flat patch rows: no convolution
+    # keeps the whole 28x28 image as its window; conv2's stays XLA's
+    # (a 14x14 window), which is faster at its 200 weights
+    assert "conv_filter_grad" in txt
+    windows = re.findall(r"convolution[^\n]*window=\{size=(\d+)x(\d+)", txt)
+    assert ("14", "14") in windows
+    assert ("28", "28") not in windows

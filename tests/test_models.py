@@ -260,3 +260,64 @@ def test_vlm_decode_after_patch_prefix():
     logits, cache = m.decode_step(params, cache, jnp.zeros((B, 1), jnp.int32), 0)
     assert logits.shape == (B, 1, V)
     assert bool(jnp.all(jnp.isfinite(logits.astype(jnp.float32))))
+
+
+# ---------------------------------------------------------------------------
+# the paper's CNNs: the patch filter gradient of a narrow conv
+# ---------------------------------------------------------------------------
+
+# (name, R, H = W, k, C_in, C_out, padding, takes the patch filter gradient):
+# the five conv layers of the MNIST and CIFAR-10 CNNs at their cells' R
+CONV_LAYERS = [("mnist.conv1", 4, 28, 5, 1, 2, 2, True),
+               ("mnist.conv2", 4, 14, 5, 2, 4, 2, False),
+               ("cifar10.conv1", 5, 32, 3, 3, 32, 1, False),
+               ("cifar10.conv2", 5, 16, 3, 32, 64, 1, False),
+               ("cifar10.conv3", 5, 8, 3, 64, 128, 1, False)]
+
+
+@pytest.mark.parametrize("vmapped", [False, True], ids=["one", "clusters"])
+@pytest.mark.parametrize("layer", CONV_LAYERS, ids=lambda l: l[0])
+def test_patch_filter_grad_matches_conv_vjp(layer, vmapped):
+    """The patch path's filter gradient equals the VJP of the plain
+    ``conv_general_dilated`` to float32 tolerance; its forward and input
+    gradient are the plain conv's, bit for bit; the shape rule picks the
+    layers it says it picks, and ``_conv`` follows it."""
+    from repro.models import cnn
+
+    _, r, h, k, c_in, c_out, pad, takes = layer
+    assert cnn.takes_patch_filter_grad(k, c_in, c_out) is takes
+    kx, kw, kd = jax.random.split(jax.random.PRNGKey(0), 3)
+    lead = (r,) if vmapped else ()
+    x = jax.random.normal(kx, lead + (2, h, h, c_in))
+    w = jax.random.normal(kw, lead + (k, k, c_in, c_out)) / (k * np.sqrt(c_in))
+    dy = jax.random.normal(kd, lead + (2, h, h, c_out))
+
+    def plain(x, w):
+        return jax.lax.conv_general_dilated(
+            x, w, (1, 1), [(pad, pad), (pad, pad)],
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+
+    def fwd_bwd(conv):
+        def one(x, w, dy):
+            y, vjp = jax.vjp(conv, x, w)
+            return (y,) + vjp(dy)
+        return jax.jit(jax.vmap(one) if vmapped else one)(x, w, dy)
+
+    y0, dx0, dw0 = map(np.asarray, fwd_bwd(plain))
+    y1, dx1, dw1 = map(np.asarray, fwd_bwd(
+        lambda x, w: cnn._conv_patch_dw(x, w, pad)))
+    np.testing.assert_array_equal(y1, y0)
+    np.testing.assert_array_equal(dx1, dx0)
+    np.testing.assert_allclose(dw1, dw0, rtol=1e-5,
+                               atol=1e-5 * np.abs(dw0).max())
+    # _conv takes the patch path exactly where the rule says
+    b = jnp.zeros(lead + (c_out,))
+    conv = lambda x, w, b: cnn._conv({"w": w, "b": b}, x, pad)
+    jaxpr = str(jax.make_jaxpr(jax.vmap(conv) if vmapped else conv)(x, w, b))
+    assert ("custom_vjp_call" in jaxpr) is takes
+
+
+def test_patch_filter_grad_layers_counts_the_rule():
+    from repro.models import cnn
+    assert cnn.patch_filter_grad_layers(cnn.MNIST_CNN) == 1
+    assert cnn.patch_filter_grad_layers(cnn.CIFAR_CNN) == 0

@@ -505,6 +505,31 @@ def test_transfer_bytes_on_put_and_eval(tiny_task, path):
     assert resident[0]["path"] == "assemble.resident"
 
 
+@pytest.mark.parametrize("path", ["sequential", "round", "block", "pool"])
+def test_step_spans_carry_patch_dw_layers(tiny_task, tiny_pcfg, path):
+    """Every round-step span, on each engine and execution mode, carries
+    how many of the model's conv layers take the patch filter gradient:
+    the MNIST CNN's conv1."""
+    from repro.core.jobs import JobSpec, run_job_pool
+    data, module = tiny_task
+    assert module.patch_dw_layers == 1
+    pcfg = dataclasses.replace(tiny_pcfg, T=2, eval_every=10)
+    mem = MemorySink()
+    tel = Telemetry(sinks=(mem,))
+    if path == "pool":
+        run_job_pool([JobSpec(name="job0", module=module, data=data,
+                              pcfg=pcfg)], block=2, telemetry=tel)
+    else:
+        run_pigeon(module, data, pcfg, telemetry=tel,
+                   engine="sequential" if path == "sequential" else "batched",
+                   block=2 if path == "block" else 1)
+    name = {"pool": "pool.step", "block": "block.step"}.get(path,
+                                                            "round.step")
+    steps = [s for s in mem.of("span") if s["name"] == name]
+    assert steps
+    assert {s["patch_dw_layers"] for s in steps} == {1}
+
+
 def test_resident_copy_is_put_once_per_client_data(tiny_task, tiny_pcfg):
     """A second run on the same ClientData gathers from the copy the first
     one put; a different ClientData object gets a copy of its own."""
